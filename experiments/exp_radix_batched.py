@@ -32,10 +32,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-from tpu_radix_join.utils.platform import apply_platform_override
-
-apply_platform_override()   # honor JAX_PLATFORMS (e.g. CPU smoke runs)
-
 import jax.numpy as jnp
 import numpy as np
 

@@ -23,10 +23,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-from tpu_radix_join.utils.platform import apply_platform_override
-
-apply_platform_override()   # honor JAX_PLATFORMS (e.g. CPU smoke runs)
-
 import numpy as np
 
 from tpu_radix_join import HashJoin, JoinConfig, Relation

@@ -2,24 +2,17 @@
 reference's oversubscribed ``mpirun``, SURVEY.md §4 item 5).  Platform-forcing
 mechanics live in tpu_radix_join/utils/platform.py."""
 
-import os
-import tempfile
-
-# Isolate the bench/grid chip handshake (utils/locks.py): without this,
-# grid tests would join the repo's REAL artifacts/BENCH_RUNNING and
-# GRID_RUNNING files — parking on a live bench and clobbering a live grid's
-# presence file.  Tests that exercise the handshake monkeypatch their own.
-_lock_dir = tempfile.mkdtemp(prefix="tpu_rj_locks_")
-os.environ.setdefault("TPU_RJ_PAUSE_FILE",
-                      os.path.join(_lock_dir, "BENCH_RUNNING"))
-os.environ.setdefault("TPU_RJ_GRID_FILE",
-                      os.path.join(_lock_dir, "GRID_RUNNING"))
-
 from tpu_radix_join.utils.platform import force_host_cpu_devices
 
 force_host_cpu_devices(8, respect_existing=True)
 
+import jax
 import pytest
+
+# no persistent compile cache in the suite, even where the environment sets
+# JAX_COMPILATION_CACHE_DIR: tests/test_chip_compile.py's described-chip
+# compiles would be written there but could not be read back without a chip
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 @pytest.fixture

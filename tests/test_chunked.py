@@ -131,89 +131,3 @@ def test_grid_join_wide_streamed_chunks():
     wb = next(iter(stream_chunks(wide, 0, 1 << 10)))
     with pytest.raises(ValueError, match="mixed key widths"):
         chunked_join_count(wb, nb, 128)
-
-
-def test_grid_pauses_on_bench_flag(tmp_path, monkeypatch, capsys):
-    """The grid must park between chunk pairs while the bench's pause file
-    exists, and resume when it disappears (cooperative single-chip yield)."""
-    import threading
-    import time as _t
-
-    import jax.numpy as jnp
-
-    from tpu_radix_join.data.tuples import TupleBatch
-    from tpu_radix_join.ops.chunked import chunked_join_grid
-
-    flag = tmp_path / "BENCH_RUNNING"
-    flag.write_text("x")
-    monkeypatch.setenv("TPU_RJ_PAUSE_FILE", str(flag))
-    n = 1 << 10
-    mk = lambda seed: TupleBatch(
-        key=jnp.asarray(np.random.default_rng(seed).permutation(n)
-                        .astype(np.uint32)),
-        rid=jnp.arange(n, dtype=jnp.uint32))
-    chunks = [mk(1), mk(1)]
-    threading.Timer(3.0, flag.unlink).start()
-    t0 = _t.perf_counter()
-    total = chunked_join_grid([chunks[0]], [chunks[1]], slab_size=n)
-    waited = _t.perf_counter() - t0
-    assert total == n                      # identical permutations join fully
-    assert waited >= 2.5, waited           # actually parked on the flag
-    out = capsys.readouterr().out
-    assert "paused" in out and "resumed" in out
-
-
-def test_grid_ignores_dead_bench_and_marks_parked(tmp_path, monkeypatch):
-    """PID liveness (r5 review): a pause file stamped by a dead process is
-    removed and ignored; while parked on a LIVE bench the grid advertises
-    GRID_RUNNING + .parked so the bench can skip its drain wait."""
-    import os
-    import subprocess
-    import threading
-    import time as _t
-
-    import jax.numpy as jnp
-
-    from tpu_radix_join.data.tuples import TupleBatch
-    from tpu_radix_join.ops.chunked import chunked_join_grid
-
-    n = 1 << 10
-    mk = lambda s: TupleBatch(
-        key=jnp.asarray(np.random.default_rng(s).permutation(n)
-                        .astype(np.uint32)),
-        rid=jnp.arange(n, dtype=jnp.uint32))
-
-    # warm the jit for these shapes so the timed region below measures the
-    # park behavior, not first-call compilation
-    from tpu_radix_join.ops.chunked import chunked_join_count
-    chunked_join_count(mk(9), mk(9), n)
-
-    # 1) dead-PID pause file: grid must remove it and run immediately
-    proc = subprocess.Popen(["true"])
-    proc.wait()
-    pause = tmp_path / "BENCH_RUNNING"
-    pause.write_text(str(proc.pid))
-    monkeypatch.setenv("TPU_RJ_PAUSE_FILE", str(pause))
-    grid_f = tmp_path / "GRID_RUNNING"
-    monkeypatch.setenv("TPU_RJ_GRID_FILE", str(grid_f))
-    t0 = _t.perf_counter()
-    assert chunked_join_grid([mk(1)], [mk(1)], slab_size=n) == n
-    assert _t.perf_counter() - t0 < 4.0    # no 5s park cycle
-    assert not pause.exists()              # dead holder's file removed
-    assert not grid_f.exists() and not (tmp_path / "GRID_RUNNING.parked").exists()
-
-    # 2) live-PID pause file: grid parks, advertises .parked, resumes
-    pause.write_text(str(os.getpid()))
-    seen = {}
-
-    def observe_then_release():
-        _t.sleep(2.5)
-        seen["grid"] = grid_f.exists()
-        seen["parked"] = (tmp_path / "GRID_RUNNING.parked").exists()
-        pause.unlink()
-
-    threading.Thread(target=observe_then_release).start()
-    assert chunked_join_grid([mk(2)], [mk(2)], slab_size=n) == n
-    assert seen == {"grid": True, "parked": True}, seen
-    assert not grid_f.exists()             # presence cleaned up on exit
-    assert not (tmp_path / "GRID_RUNNING.parked").exists()

@@ -249,6 +249,21 @@ def test_audit_chunked_strategy_and_delta_semantics():
     assert PLANDRIFT in m.counters
 
 
+@pytest.mark.parametrize("predicted_ms,actual_us", [
+    (0.5914, 1234.5678), (0.0127, 3.3), (250.0, 2.5e6)])
+def test_audit_drift_agrees_with_its_row(predicted_ms, actual_us):
+    """drift_pct is priced on the rounded values the table shows, so it
+    recomputes from the row even at the chip's sub-ms dispatch floor."""
+    m = Measurements(node_id=0, num_nodes=1)
+    m.times_us["JTOTAL"] = actual_us
+    plan = {"strategy": "s", "engine": "incore",
+            "predicted_ms": predicted_ms}
+    t = audit_plan(plan, m, times0={})
+    assert t["drift_pct"] == pytest.approx(
+        100.0 * abs(t["actual_ms"] - t["predicted_ms"]) / t["predicted_ms"],
+        abs=0.005)
+
+
 def test_audit_none_paths():
     m = Measurements(node_id=0, num_nodes=1)
     assert audit_plan(None, m) is None           # no plan -> no audit
@@ -302,7 +317,7 @@ def test_bundle_roundtrip_render_merge(tmp_path):
 
 
 def test_bundle_without_measurements(tmp_path):
-    """bench.py's probe-exhaustion path writes bundles with no registry."""
+    """A death outside any engine writes a bundle with no registry."""
     path = postmortem.write_bundle(
         str(tmp_path), None, reason="backend_unavailable",
         failure_class="backend_unavailable",

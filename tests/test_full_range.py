@@ -202,13 +202,12 @@ def test_full_range_composes_with_skew_split():
 def test_merge_full_inside_shard_map():
     """The full-range count must trace inside a shard_map body — the chip
     pipeline's exact shape (hash_join._local_process).  The portable XLA
-    realization is asserted here; interpret-mode Pallas cannot run under
-    shard_map at all (the HLO interpreter re-traces kernel-internal
-    constants without mesh annotations — a pre-existing property shared by
-    EVERY kernel in ops/pallas, asserted below so a JAX upgrade that lifts
-    it is noticed), while compiled Pallas traces its kernel outside the
-    mesh and is chip-validated (artifacts/chip_r3 ran the packed kernel
-    inside the fused shard_map pipeline)."""
+    realization is asserted here.  The interpret-mode merge-scan kernel
+    cannot run under shard_map: the HLO interpreter evaluates its top-level
+    ops on the tile's varying mesh axes and rejects mixing them with
+    constants (asserted below so a JAX upgrade that lifts it is noticed;
+    ops/pallas/radix_sort.py shows the in-kernel workaround).  Compiled
+    Pallas traces its kernel outside the mesh."""
     import jax
     from jax.sharding import PartitionSpec as P
     from tpu_radix_join.parallel.mesh import make_mesh
@@ -240,12 +239,8 @@ def test_merge_full_inside_shard_map():
         for i in range(n_dev))
     assert total == want, (total, want)
     assert int(np.asarray(mw)) == 1
-    from tpu_radix_join.utils import compat
-    if not compat.is_legacy():
-        # the "varying manual axes" rejection is a current-jax vma check;
-        # the legacy shard_map (check_rep=False shim) predates it
-        with pytest.raises(ValueError, match="varying manual axes"):
-            body("pallas_interpret")(jnp.asarray(r), jnp.asarray(s))
+    with pytest.raises(ValueError, match="varying manual axes"):
+        body("pallas_interpret")(jnp.asarray(r), jnp.asarray(s))
 
 
 def test_key_boundary_values_exact():

@@ -241,7 +241,12 @@ def test_static_for_explain_agrees_with_cost_model():
     view = build_entries(num_nodes=N, entries=["pipeline"])[0]
     w = Workload(r_tuples=N * 8192, s_tuples=N * 8192,
                  key_bound=N * 8192, num_nodes=N)
-    xplan = plan_exchange(load_profile(), w, fanout_bits=5)
+    # a codec-off plan (packing priced above the wire it saves), the
+    # geometry the traced pipeline ships by default
+    slow_hbm = load_profile().replace_constants(
+        hbm_gbps={"value": 50.0, "source": "test: slow HBM"})
+    xplan = plan_exchange(slow_hbm, w, fanout_bits=5)
+    assert xplan.codec == "off"
     payload = static_for_explain(view, xplan)
     assert payload is not None
     # per-slot basis: pow2 capacity slack cancels, so raw codec-off

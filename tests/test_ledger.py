@@ -5,7 +5,7 @@ rotation (observability/metrics.py).
 The ledger is the planner's long-term memory: these tests pin the row
 schema, the tolerant-reader discipline (torn lines, newer schemas), the
 payload builders the run-end/per-query/bench writers use, and the
-artifact backfill path the committed history flows through."""
+artifact backfill path chip-run outputs flow through."""
 
 import json
 import os
@@ -119,22 +119,43 @@ def test_rows_from_perf_dir_roundtrip(tmp_path):
     assert payload["workload"]["global_size"] == 256
 
 
-def test_ingest_artifacts_backfills_committed_history(tmp_path):
+def _artifact_dir(base):
+    """A chip-run artifact dir as bench.py and main.py leave it: two bench
+    result lines (one wrapped with its rc, one dead before its JSON line)
+    and one perf dir."""
+    os.makedirs(base)
+    for name, doc in (
+            ("BENCH_a.json", {"metric": "single_chip_join_throughput",
+                              "value": 7.0e8, "unit": "tuples/sec"}),
+            ("BENCH_b.json", {"rc": 0, "parsed": {
+                "metric": "single_chip_join_throughput", "value": 7.1e8,
+                "unit": "tuples/sec", "size": 1 << 24}}),
+            ("BENCH_c.json", {"rc": 2, "tail": "died"})):
+        with open(os.path.join(base, name), "w") as f:
+            json.dump(doc, f)
+    m = Measurements(node_id=0, num_nodes=1)
+    m.add_time_us("JTOTAL", 1000.0)
+    m.meta.update(tuples_per_node=256, global_size=256, nodes=1)
+    m.store(os.path.join(base, "run1", "perf_16m"))
+    return base
+
+
+def test_ingest_artifacts_backfills_history(tmp_path):
+    base = _artifact_dir(str(tmp_path / "art"))
     out = str(tmp_path / "ledger")
-    counts = ingest_artifacts(os.path.join(REPO, "artifacts"), out)
-    # BENCH_r01/r02 parsed; r03..r05 died before their JSON line (rc=2)
-    assert counts["bench"] == 2
-    assert counts["run"] >= 1                    # committed chip perf dirs
+    counts = ingest_artifacts(base, out)
+    # BENCH_a/b parsed; BENCH_c died before its JSON line (rc=2)
+    assert counts == {"bench": 2, "run": 1}
     rows = load_rows(out)
     bench = [r for r in rows if r["kind"] == "bench"]
-    assert {r["run_id"] for r in bench} == {"BENCH_r01", "BENCH_r02"}
+    assert {r["run_id"] for r in bench} == {"BENCH_a", "BENCH_b"}
     assert all(r["metric"] == "single_chip_join_throughput" for r in bench)
 
 
 def test_emit_ledger_cli(tmp_path):
+    base = _artifact_dir(str(tmp_path / "art"))
     out = subprocess.run(
-        [sys.executable, "tools_make_report.py",
-         os.path.join(REPO, "artifacts"), "--emit-ledger",
+        [sys.executable, "tools_make_report.py", base, "--emit-ledger",
          str(tmp_path / "led")],
         capture_output=True, text=True, cwd=REPO, timeout=120)
     assert out.returncode == 0, out.stderr

@@ -135,3 +135,50 @@ def test_cli_trace_composes_with_measure_phases(tmp_path):
     assert "trace" in info and info["trace"]["ops"]
     perf = (out_dir / "0.perf").read_text()
     assert "JMPI" in perf and "JPROC" in perf     # split columns intact
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """enable_compile_cache: $JAX_COMPILATION_CACHE_DIR wins and nothing
+    else is set; unset, the fixed in-checkout path; on the CPU, nothing."""
+    import os
+
+    import jax
+
+    from tpu_radix_join.utils import platform
+
+    assert platform.enable_compile_cache() is None      # this CPU suite
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert platform.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert platform.enable_compile_cache() == os.path.join(
+            repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == platform.DEFAULT_CACHE_DIR
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
+def test_fleet_refuses_second_worker_on_tpu_host(monkeypatch, capsys):
+    """A chip serves one process: on a TPU host --fleet 2 is refused
+    before any worker starts (it stays allowed on the CPU)."""
+    import pytest
+
+    import tpu_radix_join.main as cli
+
+    monkeypatch.setattr(cli, "_local_tpu_chips", lambda: 1)
+    with pytest.raises(SystemExit) as e:
+        cli.main(["--serve", "-", "--fleet", "2"])
+    assert e.value.code == 2
+    assert "--fleet 2 on a TPU host" in capsys.readouterr().err
+    monkeypatch.undo()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert cli._local_tpu_chips() == 0

@@ -2,6 +2,8 @@
 
 Skipped when no toolchain is available (the package falls back to numpy)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -98,3 +100,25 @@ def test_native_modulo():
     rel = Relation(1 << 10, 2, "modulo", modulo=17)
     k, rid = rel.shard_np(1)
     np.testing.assert_array_equal(k, rid % 17)
+
+
+def test_library_is_keyed_by_source_hash(tmp_path, monkeypatch):
+    """A stale library in the checkout never loads: the built library's
+    name is a hash of the sources and flags, so editing a source (or
+    dropping a foreign .so beside them) selects a different file."""
+    import shutil
+
+    from tpu_radix_join.native import build
+
+    for src in build._SOURCES:
+        shutil.copy(os.path.join(build._DIR, src), tmp_path / src)
+    monkeypatch.setattr(build, "_DIR", str(tmp_path))
+    first = build.library_path()
+    assert os.path.dirname(first) == str(tmp_path)
+    (tmp_path / os.path.basename(first)).write_bytes(b"stale")
+    with open(tmp_path / "datagen.cc", "a") as f:
+        f.write("\n// edited\n")
+    edited = build.library_path()
+    assert edited != first
+    monkeypatch.setattr(build, "_FLAGS", build._FLAGS + ["-g"])
+    assert build.library_path() not in (first, edited)

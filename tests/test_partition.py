@@ -112,10 +112,55 @@ def test_kernel_multi_tile_carry():
                                   np.bincount(ids, minlength=5))
 
 
+@pytest.fixture
+def mosaic_branch_interpreted(monkeypatch):
+    """Run the kernels' Mosaic branches (``interpret=False`` in the kernel
+    body) under the Pallas interpreter: the CPU's only view of the code
+    the chip compiles, which interpret mode otherwise never traces."""
+    from jax.experimental import pallas as pl
+
+    import tpu_radix_join.ops.pallas.partition as part
+    real = pl.pallas_call
+    monkeypatch.setattr(part.pl, "pallas_call",
+                        lambda *a, **kw: real(*a, **{**kw,
+                                                     "interpret": True}))
+
+
+@pytest.mark.parametrize("num_groups,group_size,capacity", [
+    (5, 1, None), (257, 1, None), (8, 2, 30000)])
+def test_mosaic_branch_matches_interpret_branch(mosaic_branch_interpreted,
+                                                num_groups, group_size,
+                                                capacity):
+    import jax
+    ids = jnp.asarray(np.random.default_rng(num_groups).integers(
+        0, num_groups + 1, 100_003).astype(np.uint32))
+    kw = dict(num_groups=num_groups, group_size=group_size,
+              capacity=capacity)
+    # the jitted wrapper caches per static args: jit a fresh lambda so the
+    # interpret=False trace really takes the Mosaic branch
+    mosaic = jax.jit(lambda x: partition_slots_pallas(x, **kw))(ids)
+    interp = partition_slots_pallas(ids, **kw, interpret=True)
+    for a, b in zip(mosaic, interp):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("shift", [0, 8, 24])
+def test_radix_pass_mosaic_branch_matches_interpret_branch(
+        mosaic_branch_interpreted, shift):
+    import jax
+
+    from tpu_radix_join.ops.pallas.radix_sort import radix_pass_slots_pallas
+    keys = jnp.asarray(np.random.default_rng(shift).integers(
+        0, 1 << 32, 100_003, dtype=np.uint64).astype(np.uint32))
+    mosaic = jax.jit(lambda k: radix_pass_slots_pallas(k, shift=shift))(keys)
+    interp = radix_pass_slots_pallas(keys, shift=shift, interpret=True)
+    np.testing.assert_array_equal(np.asarray(mosaic), np.asarray(interp))
+
+
 def test_kernel_rejects_bad_geometry():
     ids = jnp.zeros((16,), jnp.uint32)
-    with pytest.raises(ValueError, match=f"> {MAX_PARTITIONS}"):
-        partition_slots_pallas(ids, num_groups=MAX_PARTITIONS + 1,
+    with pytest.raises(ValueError, match=f"> {MAX_PARTITIONS + 1}"):
+        partition_slots_pallas(ids, num_groups=MAX_PARTITIONS + 2,
                                interpret=True)
     with pytest.raises(ValueError, match="multiple"):
         partition_slots_pallas(ids, num_groups=10, group_size=4,

@@ -115,7 +115,8 @@ def session():
     m = Measurements()
     sess = JoinSession(JoinConfig(num_nodes=NODES),
                        ServiceConfig(breaker_threshold=2,
-                                     breaker_cooldown_s=0.05),
+                                     breaker_cooldown_s=0.05,
+                                     cpu_fallback=True),
                        measurements=m)
     yield sess
     sess.close()
@@ -228,3 +229,31 @@ def test_session_chaos_single_stream_classifies_backend_outage():
     assert BACKEND_UNAVAILABLE in out.failure_class
     # breaker threshold 1 + zero cooldown: the stream recovers in-line
     assert "q2=ok" in out.detail
+
+
+def test_open_breaker_fails_fast_without_cpu_fallback():
+    """The default session never answers on the CPU: while the breaker is
+    open, queries fail as backend_unavailable from no engine at all."""
+    m = Measurements()
+    sess = JoinSession(JoinConfig(num_nodes=NODES),
+                       ServiceConfig(breaker_threshold=1,
+                                     breaker_cooldown_s=60.0),
+                       measurements=m)
+    try:
+        inj = faults.FaultInjector(seed=5, measurements=m)
+        inj.arm(faults.BACKEND_DISPATCH, at=1, exc=TransientFault)
+        with inj:
+            outs = []
+            for i in range(2):
+                sess.submit(_req(f"open{i}", seed=21))
+                outs.append(sess.run_next())
+        assert sess.breaker.state == "open"
+        shed = outs[1]
+        assert shed.status == "failed"
+        assert shed.failure_class == BACKEND_UNAVAILABLE
+        assert shed.engine == "primary" and not shed.degraded
+        assert "--cpu-fallback" in shed.detail
+        assert sess._cpu_engine is None
+        assert m.counters.get(QDEGRADED, 0) == 0
+    finally:
+        sess.close()

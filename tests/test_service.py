@@ -215,7 +215,7 @@ def test_retryable_default_policy_covers_transients():
 
 def test_retryable_policy_narrows_the_predicate():
     sizing = RetryPolicy(retryable_classes=RETRYABLE_SIZING)
-    # the engine's capacity-regrow loop must NOT spin on a tunnel outage
+    # the engine's capacity-regrow loop must NOT spin on a backend outage
     assert is_retryable_class(CAPACITY_OVERFLOW, sizing)
     assert not is_retryable_class(BACKEND_UNAVAILABLE, sizing)
     custom = RetryPolicy(retryable_classes=frozenset({KEY_CONTRACT}))

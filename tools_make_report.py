@@ -1,15 +1,16 @@
 """Assemble the round's chip evidence into one summary table.
 
-    python tools_make_report.py [artifacts/chip_r5]
-    python tools_make_report.py artifacts/chip_r5 --emit-profile out.json \
-        [--profile-name v5e_r5]
-    python tools_make_report.py artifacts/chip_r5 --emit-timeline out.json
-    python tools_make_report.py artifacts --emit-ledger artifacts/ledger
+    python tools_make_report.py [chiprun_out]
+    python tools_make_report.py chiprun_out --emit-profile out.json \
+        [--profile-name v5e_fit]
+    python tools_make_report.py chiprun_out --emit-timeline out.json
+    python tools_make_report.py chiprun_out --emit-ledger artifacts/ledger
 
-Reads every perf dir (`<rank>.perf`/`<rank>.info`), trace breakdown
-(`trace_*/breakdown.json`), and task log under the artifact dir and prints a
+Reads every perf dir (`<rank>.perf`/`<rank>.info`) and trace breakdown
+(`trace_*/breakdown.json`) under the artifact dir (by default
+``chiprun_out/``, where chip runs leave their outputs) and prints a
 markdown summary (per-workload phase columns in ms/join net of repeats,
-JPROCRATE, CTOTAL where present, trace sort shares, runner task status).
+JPROCRATE, CTOTAL where present, trace sort shares).
 The output is the raw material for BASELINE.md's achieved tables — numbers
 come straight from the committed artifacts, no hand transcription.
 
@@ -26,12 +27,12 @@ JSON on a shared clock (observability.timeline.merge_timeline) — load the
 output in Perfetto / chrome://tracing.
 
 ``--emit-ledger OUT`` backfills the cross-run telemetry ledger
-(observability/ledger.py) from committed history: every ``BENCH_r*.json``
-at the repo root becomes a ``kind="bench"`` row and every ``perf_*`` dir
-under the artifact dir (one nesting level allowed) a ``kind="run"`` row,
+(observability/ledger.py) from an artifact dir: every ``BENCH_*.json``
+bench.py result line saved there becomes a ``kind="bench"`` row and every
+``perf_*`` dir (one nesting level allowed) a ``kind="run"`` row,
 timestamped by file mtime.  The backfilled ledger is what
 ``tools_profile_fit.py fit`` turns into a provenance-carrying schema-v3
-profile without a single fresh chip run.
+profile.
 """
 
 import glob
@@ -180,7 +181,7 @@ def emit_timeline(base_dir: str, out_path: str) -> int:
 
 
 def emit_ledger(base_dir: str, out_path: str) -> int:
-    """Backfill the cross-run ledger from committed BENCH/perf history."""
+    """Backfill the cross-run ledger from an artifact dir's BENCH/perf files."""
     from tpu_radix_join.observability.ledger import Ledger, ingest_artifacts
 
     counts = ingest_artifacts(base_dir, out_path)
@@ -188,8 +189,8 @@ def emit_ledger(base_dir: str, out_path: str) -> int:
     print(f"wrote {Ledger(out_path).path}: {counts['bench']} bench row(s), "
           f"{counts['run']} run row(s)")
     if total == 0:
-        print(f"WARNING: nothing to ingest under {base_dir} (and no "
-              f"BENCH_r*.json at the repo root)", file=sys.stderr)
+        print(f"WARNING: nothing to ingest under {base_dir}",
+              file=sys.stderr)
         return 1
     return 0
 
@@ -213,7 +214,7 @@ def main() -> int:
         i = argv.index("--emit-ledger")
         ledger = argv[i + 1]
         del argv[i:i + 2]
-    base = argv[0] if argv else "artifacts/chip_r5"
+    base = argv[0] if argv else "chiprun_out"
     if ledger is not None:
         return emit_ledger(base, ledger)
     if timeline is not None:
@@ -221,16 +222,6 @@ def main() -> int:
     if emit is not None:
         return emit_profile(base, emit, prof_name)
     print(f"# Evidence summary: {base}\n")
-
-    print("## Task status\n")
-    logs = sorted(glob.glob(os.path.join(base, "*.log")))
-    names = sorted({os.path.basename(p).split(".a")[0].removesuffix(".log")
-                    for p in logs})
-    for name in names:
-        done = os.path.exists(os.path.join(base, f"{name}.done"))
-        attempts = len(glob.glob(os.path.join(base, f"{name}.a*.log")))
-        print(f"- {name}: {'DONE' if done else 'pending'}"
-              f" ({attempts} attempt{'s' if attempts != 1 else ''})")
 
     rows = [r for r in (perf_row(d) for d in sorted(
         glob.glob(os.path.join(base, "perf_*")))) if r]

@@ -8,10 +8,6 @@ program over a TPU mesh.  See SURVEY.md at the repo root for the component-level
 mapping to the reference (file:line citations throughout the code).
 """
 
-from tpu_radix_join.utils import compat as _compat
-
-_compat.install()
-
 from tpu_radix_join.core.config import JoinConfig
 from tpu_radix_join.data.relation import Relation
 from tpu_radix_join.operators.hash_join import HashJoin

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -87,13 +88,11 @@ class EqnView:
         """(repo-relative-ish path, line) parsed from the source summary;
         falls back to ("", 0) for equations with no user frame."""
         s = self.source.split(" ")[0] if self.source else ""
-        if ":" not in s:
+        # "path:line" or, since JAX 0.9, "path:line:column"
+        m = re.fullmatch(r"(.+?):(\d+)(?::\d+)?", s)
+        if m is None:
             return "", 0
-        path, _, line = s.rpartition(":")
-        try:
-            return path, int(line)
-        except ValueError:
-            return "", 0
+        return m.group(1), int(m.group(2))
 
 
 @dataclass

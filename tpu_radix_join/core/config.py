@@ -402,6 +402,10 @@ class ServiceConfig:
     # --- circuit breaker (service/breaker.py) ----------------------------
     breaker_threshold: int = 3       # consecutive backend failures to trip
     breaker_cooldown_s: float = 30.0  # open -> half-open promotion delay
+    #: serve from the CPU engine while the breaker is open; off by default
+    #: so that a device outage fails queries loudly instead of answering
+    #: them on the host (main.py --cpu-fallback)
+    cpu_fallback: bool = False
 
     # --- outcome retention (service/session.py) --------------------------
     outcomes_keep: int = 512         # recent QueryOutcomes kept in memory;

@@ -47,14 +47,8 @@ def load_aware_assignment(
         loads = loads.at[node].add(weight[p])
         return loads, (p, node)
 
-    # On legacy jax/XLA a rolled scan here aborts the process: the old
-    # sharding-propagation pass CHECK-fails on a while-loop whose outputs
-    # feed sharded consumers (utils/compat.is_legacy).  Full unroll emits
-    # straight-line HLO — same math, no loop for the pass to choke on.
-    from tpu_radix_join.utils import compat
-    unroll = num_partitions if compat.is_legacy() else 1
     _, (ps, nodes) = jax.lax.scan(step, jnp.zeros((num_nodes,), jnp.float32),
-                                  order, unroll=unroll)
+                                  order)
     assignment = jnp.zeros((num_partitions,), jnp.uint32).at[ps].set(nodes)
     return assignment
 

@@ -105,7 +105,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--cpu-fallback", action="store_true",
                    help="if device/mesh init fails, rebuild the engine over "
                         "host CPU devices (loud [DEGRADE] warning) instead "
-                        "of aborting")
+                        "of aborting; in serve mode, answer from the CPU "
+                        "engine while the circuit breaker is open instead "
+                        "of failing those queries")
     p.add_argument("--grid-chunk-tuples", type=int, default=None,
                    help="run the out-of-core grid join (ops/chunked.py) "
                         "streaming both relations in chunks of this many "
@@ -311,7 +313,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "stderr)")
     p.add_argument("--breaker-threshold", type=int, default=3,
                    help="serve mode: consecutive backend failures that trip "
-                        "the circuit breaker onto the degraded CPU engine")
+                        "the circuit breaker (queries fail fast while it is "
+                        "open, or go to the CPU engine with --cpu-fallback)")
     p.add_argument("--breaker-cooldown-s", type=float, default=30.0,
                    help="serve mode: seconds the breaker stays open before "
                         "half-opening for a primary health probe")
@@ -408,6 +411,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "round-trip from the reported rate; no per-join "
                         "retry loop")
     return p
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips on this host's PCI bus, found without opening the backend
+    (the fleet supervisor must not hold a chip); 0 when JAX_PLATFORMS
+    keeps JAX off the TPU."""
+    import os
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms:
+        return 0
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+    return num_available_tpu_chips_and_device_id()[0]
 
 
 def _forensics_dir(args):
@@ -644,9 +659,10 @@ def _run_serve(args, cfg, meas, nodes, sampler=None, membership=None) -> int:
     """Resident service mode: every request in the file flows through ONE
     :class:`~tpu_radix_join.service.JoinSession` — warm plan/capacity
     reuse across queries, admission control at the door, per-query
-    deadlines, and a circuit breaker that degrades to the CPU engine when
-    the backend goes dark.  One outcome JSON line per query on stdout,
-    then a summary line carrying the SLO snapshot."""
+    deadlines, and a circuit breaker that fails queries fast while the
+    backend is down (or, with --cpu-fallback, answers them on the CPU).
+    One outcome JSON line per query on stdout, then a summary line
+    carrying the SLO snapshot."""
     import json as _json
     import os
     import time as _time
@@ -679,6 +695,7 @@ def _run_serve(args, cfg, meas, nodes, sampler=None, membership=None) -> int:
         default_deadline_s=args.serve_deadline_s,
         breaker_threshold=args.breaker_threshold,
         breaker_cooldown_s=args.breaker_cooldown_s,
+        cpu_fallback=args.cpu_fallback,
         place_cache_max=args.place_cache_max,
         result_cache_max=args.result_cache,
         result_cache_ttl_s=args.result_cache_ttl_s,
@@ -1314,21 +1331,24 @@ def main(argv=None) -> int:
         print(f"[PROFILE] auto -> {args.profile}", file=sys.stderr)
 
     if args.fleet is not None:
+        if args.fleet > 1 and _local_tpu_chips():
+            parser.error(
+                f"--fleet {args.fleet} on a TPU host: every --serve worker "
+                f"opens all local chips and a chip serves one process, so "
+                f"worker 2 could not start; run --fleet 1")
         # the supervisor never initializes devices — the workers own the
         # mesh; dispatch before the driver's jax/device bring-up
         return _run_fleet(args)
 
     import jax
 
-    from tpu_radix_join.utils.platform import apply_platform_override
-
-    apply_platform_override()
-
     from tpu_radix_join import HashJoin, JoinConfig, Relation
     from tpu_radix_join.parallel.multihost import initialize as init_multihost
     from tpu_radix_join.performance import Measurements
+    from tpu_radix_join.utils.platform import enable_compile_cache
 
     distributed = init_multihost()   # no-op unless a world is configured
+    enable_compile_cache()
     nodes = args.nodes or jax.device_count()
     if args.grid_chunk_tuples is not None and nodes != 1:
         parser.error("--grid-chunk-tuples runs the single-node out-of-core "
@@ -1686,7 +1706,7 @@ def _run_driver(args, cfg, meas, distributed, nodes, membership=None) -> int:
     # before its join timers start (main.cpp:94-116), so repeats must not
     # re-pay generation/transfer — with host generation the device_put
     # completes lazily inside the first join's fence, silently inflating
-    # JPROC by the transfer time on remote-attached devices.
+    # JPROC by the host-to-device transfer time.
     r_batch, s_batch = engine.place(inner), engine.place(outer)
     result = None
     # --trace: the reference brackets exactly the join with PAPI and writes
@@ -1848,6 +1868,10 @@ def _run_driver(args, cfg, meas, distributed, nodes, membership=None) -> int:
             status = "OK" if result.matches == expected else "MISMATCH"
             print(f"[RESULTS] Expected: {expected} ({status})")
         print(f"[RESULTS] Conservation: {'OK' if result.ok else 'VIOLATED'}")
+        out_devs = meas.meta.get("output_devices")
+        if out_devs:
+            print(f"[RESULTS] Output devices: {len(out_devs)} "
+                  f"({'; '.join(out_devs)})")
         if not result.ok and result.diagnostics:
             for k, v in result.diagnostics.items():
                 print(f"[RESULTS] failure/{k}: {v}")

@@ -2,15 +2,16 @@
 
 The reference builds its C++ runtime with CMake into static libs
 (CMakeLists.txt:16-23); here the native pieces compile once into a shared
-library next to the sources (g++ -O3 -shared) and load via ctypes.  If no
-toolchain is available — or an existing .so is stale/foreign — the callers
-fall back to pure-numpy implementations: the framework stays functional, just
-with slower host-side generation.
+library next to the sources (g++ -O3 -shared), named by a hash of the sources
+and flags, and load via ctypes.  If no toolchain is available — or the .so is
+foreign — the callers fall back to pure-numpy implementations: the framework
+stays functional, just with slower host-side generation.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,27 +19,36 @@ from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["pool.cc", "datagen.cc"]
-_LIB_NAME = "libtrj_native.so"
+_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _newer_than(a: str, b: str) -> bool:
-    return os.path.getmtime(a) > os.path.getmtime(b)
+def library_path() -> str:
+    """Where the library built from the current sources and flags lives.
+
+    The name carries a hash of the committed sources and the compiler
+    flags, so a library left over from other sources (an untracked, stale
+    ``.so`` in the checkout) is never the one that loads, whatever its
+    modification time."""
+    h = hashlib.sha256(" ".join(_FLAGS).encode())
+    for src in _SOURCES:
+        with open(os.path.join(_DIR, src), "rb") as f:
+            h.update(src.encode() + b"\0" + f.read())
+    return os.path.join(_DIR, f"libtrj_native-{h.hexdigest()[:16]}.so")
 
 
 def _compile() -> Optional[str]:
-    out = os.path.join(_DIR, _LIB_NAME)
-    srcs = [os.path.join(_DIR, s) for s in _SOURCES]
-    if os.path.exists(out) and not any(_newer_than(s, out) for s in srcs):
+    out = library_path()
+    if os.path.exists(out):
         return out
     # Compile to a temp path and rename into place so concurrent processes
     # never load a half-written library.
     tmp = f"{out}.tmp.{os.getpid()}"
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-o", tmp, *srcs]
+    cmd = ["g++", *_FLAGS, "-o", tmp,
+           *(os.path.join(_DIR, s) for s in _SOURCES)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, out)

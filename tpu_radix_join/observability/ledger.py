@@ -236,19 +236,15 @@ def rows_from_perf_dir(d: str) -> List[Tuple[str, dict]]:
     return out
 
 
-def ingest_artifacts(base_dir: str, out_path: str,
-                     bench_dir: Optional[str] = None) -> Dict[str, int]:
-    """Backfill: distill committed ``BENCH_r*.json`` (under ``bench_dir``,
-    default the repo root) and every ``perf_*`` dir under ``base_dir``
-    (one level of nesting allowed: ``artifacts/chip_*/perf_*``) into
-    ledger rows at ``out_path``.  Row timestamps are the artifacts' file
-    mtimes, so backfilled provenance keeps its real age.  Returns
-    ``{"bench": n, "run": n}``."""
+def ingest_artifacts(base_dir: str, out_path: str) -> Dict[str, int]:
+    """Backfill: distill the bench.py result files (``BENCH_*.json``) and
+    every ``perf_*`` dir under ``base_dir`` (one level of nesting allowed:
+    ``<dir>/*/perf_*``) into ledger rows at ``out_path``.  Row timestamps
+    are the artifacts' file mtimes, so backfilled provenance keeps its real
+    age.  Returns ``{"bench": n, "run": n}``."""
     led = Ledger(out_path)
     counts = {"bench": 0, "run": 0}
-    bench_dir = bench_dir or os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    for path in sorted(glob.glob(os.path.join(bench_dir, "BENCH_r*.json"))):
+    for path in sorted(glob.glob(os.path.join(base_dir, "BENCH_*.json"))):
         try:
             with open(path) as f:
                 doc = json.load(f)

@@ -99,9 +99,3 @@ def histogram_pallas(pid: jnp.ndarray,
       w.astype(jnp.uint32).reshape(num_tiles * ROWS, LANES)
       ).astype(jnp.uint32)
 
-
-def pallas_histogram_available() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False

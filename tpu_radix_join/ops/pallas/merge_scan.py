@@ -34,12 +34,10 @@ TILE = ROWS * LANES
 
 
 def pallas_available() -> bool:
-    """True when running on a real TPU backend (else use interpret=True or
-    the XLA fallback in merge_count.py)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """True on a TPU backend: the one rule by which every ``auto`` site
+    (ops/radix.py, ops/sorting.py, merge_count.py) picks a compiled
+    Pallas kernel; elsewhere they take interpret=True or the XLA path."""
+    return jax.default_backend() == "tpu"
 
 
 def out_struct(shape, dtype, like) -> jax.ShapeDtypeStruct:

@@ -43,18 +43,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpu_radix_join.data.tuples import effective_key_bits
-from tpu_radix_join.ops.pallas.merge_scan import _tile_cumsum, out_struct
-from tpu_radix_join.ops.pallas.partition import pallas_partition_available
+from tpu_radix_join.ops.pallas.merge_scan import out_struct
+from tpu_radix_join.ops.pallas.partition import (mosaic_group_hist,
+                                                 mosaic_group_slots,
+                                                 smem_fill)
 
 RADIX_BITS = 8
 RADIX = 1 << RADIX_BITS      # == partition.MAX_PARTITIONS: the digit fanout
-                             # the unrolled Mosaic scan loop tolerates
 LANES = 128
-#: smaller tile than partition.py's 2048: the slot phase ranks against all
-#: 256 digit columns at once, so the interpret-mode one-hot is
-#: [ROWS*128, 256] i32 — 32MB at 256 rows, which keeps the host-CPU bench
-#: and tier-1 parity runs in cache-friendly territory.  On the Mosaic path
-#: the tile is 128KB of VMEM per ref, well under budget.
+#: the slot phase ranks against all 256 digit columns at once, so the
+#: interpret-mode one-hot is [ROWS*128, 256] i32 — 32MB at 256 rows, which
+#: keeps the host-CPU bench and tier-1 parity runs in cache-friendly
+#: territory.  On the Mosaic path the tile is 128KB of VMEM per ref.
 ROWS = 256
 
 
@@ -81,18 +81,25 @@ def _digit_kernel(keys_ref, slots_ref, hist_ref, cur_ref, *, shift: int,
     """
     ph = pl.program_id(0)
     t = pl.program_id(1)
-    keys = keys_ref[:]
-    rows, lanes = keys.shape
-    # the 8-bit digit, extracted in uint32 (logical shift) then cast for
-    # the int32 scan arithmetic below
-    d = keys if shift == 0 else jnp.right_shift(keys, jnp.uint32(shift))
-    d = (d & jnp.uint32(RADIX - 1)).astype(jnp.int32)
-    # flat row-major position across the padded input: pad rows (>= n)
-    # become the invalid id RADIX — counted nowhere, slot -1, dropped
-    flat = (t * (rows * lanes)
-            + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 0) * lanes
-            + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1))
-    ids = jnp.where(flat < n, d, jnp.int32(RADIX))
+
+    def digit_ids():
+        # Read the key tile inside the phase bodies, not at the kernel's
+        # top level: under shard_map the interpreter evaluates top-level
+        # ops with the tile's varying mesh axes, and an op that mixes them
+        # with a constant fails its varying-axes check.  Inside pl.when the
+        # cond carries the axes through, as partition._kernel relies on.
+        keys = keys_ref[:]
+        rows, lanes = keys.shape
+        # the 8-bit digit, extracted in uint32 (logical shift) then cast
+        # for the int32 scan arithmetic below
+        d = keys if shift == 0 else jnp.right_shift(keys, jnp.uint32(shift))
+        d = (d & jnp.uint32(RADIX - 1)).astype(jnp.int32)
+        # flat row-major position across the padded input: pad rows (>= n)
+        # become the invalid id RADIX — counted nowhere, slot -1, dropped
+        flat = (t * (rows * lanes)
+                + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 0) * lanes
+                + jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1))
+        return jnp.where(flat < n, d, jnp.int32(RADIX))
 
     @pl.when(jnp.logical_and(ph == 0, t == 0))
     def _init_hist():
@@ -102,18 +109,16 @@ def _digit_kernel(keys_ref, slots_ref, hist_ref, cur_ref, *, shift: int,
             # interpret path, which tier-1 pays for every pass
             hist_ref[...] = jnp.zeros((RADIX,), jnp.int32)
         else:
-            for g in range(RADIX):
-                hist_ref[g] = jnp.int32(0)
+            smem_fill(hist_ref, RADIX, 0)
 
     @pl.when(ph == 0)
     def _histogram():
+        ids = digit_ids()
         if interpret:
             hist_ref[...] = hist_ref[...] + jnp.bincount(
                 ids.reshape(-1), length=RADIX).astype(jnp.int32)
         else:
-            for g in range(RADIX):
-                hit = (ids == g).astype(jnp.int32)
-                hist_ref[g] = hist_ref[g] + jnp.sum(jnp.sum(hit, axis=0))
+            mosaic_group_hist(ids, hist_ref, RADIX)
         slots_ref[:] = jnp.zeros(ids.shape, jnp.uint32)
 
     @pl.when(jnp.logical_and(ph == 1, t == 0))
@@ -127,13 +132,15 @@ def _digit_kernel(keys_ref, slots_ref, hist_ref, cur_ref, *, shift: int,
             h = hist_ref[...]
             cur_ref[...] = jnp.cumsum(h) - h
         else:
-            off = jnp.int32(0)
-            for g in range(RADIX):
+            def step(g, off):
                 cur_ref[g] = off
-                off = off + hist_ref[g]
+                return off + hist_ref[g]
+
+            jax.lax.fori_loop(0, RADIX, step, jnp.int32(0))
 
     @pl.when(ph == 1)
     def _assign_slots():
+        ids = digit_ids()
         if interpret:
             flat_ids = ids.reshape(-1)
             g = jnp.minimum(flat_ids, RADIX - 1)
@@ -146,14 +153,7 @@ def _digit_kernel(keys_ref, slots_ref, hist_ref, cur_ref, *, shift: int,
             slots = (cur_vec[g] + rank).reshape(ids.shape)
             cur_ref[...] = cur_vec + incl[-1, :]
         else:
-            slots = jnp.zeros(ids.shape, jnp.int32)
-            for gi in range(RADIX):
-                hit = ids == gi
-                m = hit.astype(jnp.int32)
-                incl = _tile_cumsum(m)
-                cur = cur_ref[gi]
-                slots = slots + jnp.where(hit, cur + (incl - m), 0)
-                cur_ref[gi] = cur + jnp.sum(jnp.sum(m, axis=0))
+            slots = mosaic_group_slots(ids, cur_ref, RADIX)
         ok = ids < RADIX
         slots_ref[:] = jnp.where(ok, slots, jnp.int32(-1)).astype(jnp.uint32)
 
@@ -246,8 +246,3 @@ def radix_sort_pallas(operands: Sequence[jnp.ndarray], *, num_keys: int = 1,
             arrs = _apply_permutation(slots, arrs)
     return tuple(arrs)
 
-
-def pallas_radix_sort_available() -> bool:
-    """True when the compiled radix sort can run — same backend probe as
-    the partition kernel (never initializes the backend)."""
-    return pallas_partition_available()

@@ -83,10 +83,10 @@ def resolve_partition_impl(impl: str | None, num_partitions: int,
     explicit alias for the default sort discipline; "loop"/"gather" name
     its two fill disciplines; "pallas"/"pallas_interpret" force the fused
     kernel (interpret = traced JAX ops, the tier-1 CPU parity path)."""
-    from tpu_radix_join.ops.pallas.partition import (
-        MAX_PARTITIONS, pallas_partition_available)
+    from tpu_radix_join.ops.pallas.merge_scan import pallas_available
+    from tpu_radix_join.ops.pallas.partition import MAX_PARTITIONS
     if impl in (None, "auto"):
-        if not pallas_partition_available():
+        if not pallas_available():
             _note_fallback(site, num_partitions, "Pallas unavailable")
             return "loop"
         if num_partitions > MAX_PARTITIONS:
@@ -113,17 +113,18 @@ def local_histogram(pid: jnp.ndarray, num_partitions: int,
     ``bincount`` scatter-add elsewhere (XLA serializes it on TPU: 154 ms at
     16M).  "xla" / "pallas" / "pallas_interpret" force a path.
     """
-    from tpu_radix_join.ops.pallas.histogram import (
-        MAX_PARTITIONS, histogram_pallas, pallas_histogram_available)
+    from tpu_radix_join.ops.pallas.histogram import (MAX_PARTITIONS,
+                                                     histogram_pallas)
+    from tpu_radix_join.ops.pallas.merge_scan import pallas_available
     if impl is None:
-        if (pallas_histogram_available()
+        if (pallas_available()
                 and num_partitions <= MAX_PARTITIONS):
             impl = "pallas"
         else:
             impl = "xla"
             _note_fallback("local_histogram", num_partitions,
                            f"> MAX_PARTITIONS {MAX_PARTITIONS}"
-                           if pallas_histogram_available()
+                           if pallas_available()
                            else "Pallas unavailable")
     weights = None if valid is None else valid.astype(jnp.uint32)
     if impl == "xla":

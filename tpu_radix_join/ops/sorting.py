@@ -38,8 +38,8 @@ from contextlib import nullcontext
 import jax
 import jax.numpy as jnp
 
-from tpu_radix_join.ops.pallas.radix_sort import (pallas_radix_sort_available,
-                                                  radix_sort_pallas)
+from tpu_radix_join.ops.pallas.merge_scan import pallas_available
+from tpu_radix_join.ops.pallas.radix_sort import radix_sort_pallas
 from tpu_radix_join.performance.measurements import SORTFALLBACK, SORTPASS
 
 #: below this many elements the fixed costs of the radix machinery (4
@@ -81,12 +81,6 @@ def set_default_sort_impl(impl: str) -> None:
         raise ValueError(
             f"unknown sort impl {impl!r} (expected one of {SORT_IMPLS})")
     _default_impl["impl"] = impl
-
-
-def pallas_sort_available() -> bool:
-    """True when the compiled radix sort can run (TPU backend; never
-    initializes the backend — see partition.pallas_partition_available)."""
-    return pallas_radix_sort_available()
 
 
 def _sort_span(impl: str, site: str, elems: int):
@@ -149,7 +143,7 @@ def resolve_sort_impl(impl: str | None, elems: int, site: str,
     if impl == "auto":
         if not eligible:
             return "xla"
-        if not pallas_sort_available():
+        if not pallas_available():
             _note_fallback(site, elems, "Pallas unavailable")
             return "xla"
         if elems < PALLAS_SORT_MIN_ELEMS:

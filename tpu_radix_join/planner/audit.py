@@ -78,8 +78,10 @@ def audit_plan(plan, measurements, repeats: int = 1,
     if jt_ms <= 0:
         return None
     reps = max(1, int(repeats))
-    actual_ms = jt_ms / reps
-    predicted_ms = float(pd.get("predicted_ms") or 0.0)
+    # drift is priced on the 3-decimal values the table shows, so a reader
+    # recomputing it from the row agrees even at sub-ms predictions
+    actual_ms = round(jt_ms / reps, 3)
+    predicted_ms = round(float(pd.get("predicted_ms") or 0.0), 3)
     drift_pct = (round(100.0 * abs(actual_ms - predicted_ms) / predicted_ms,
                        2) if predicted_ms > 0 else None)
     terms = []
@@ -93,8 +95,8 @@ def audit_plan(plan, measurements, repeats: int = 1,
         "strategy": pd.get("strategy", ""),
         "engine": pd.get("engine", ""),
         "profile_name": pd.get("profile_name", ""),
-        "predicted_ms": round(predicted_ms, 3),
-        "actual_ms": round(actual_ms, 3),
+        "predicted_ms": predicted_ms,
+        "actual_ms": actual_ms,
         "drift_pct": drift_pct,
         "repeats": reps,
         "terms": terms,

@@ -495,6 +495,7 @@ class SessionChaosRunner:
         # what gives the serve.cache_poison arm a stored entry to corrupt
         self.service = ServiceConfig(breaker_threshold=1,
                                      breaker_cooldown_s=0.0,
+                                     cpu_fallback=True,
                                      result_cache_max=4)
         self.measurements: List[Any] = []   # one registry per run, in order
 

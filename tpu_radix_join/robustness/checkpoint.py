@@ -11,7 +11,7 @@ restarting (the single-shot reference has no such capability, SURVEY.md
 Rules:
 
   * **Atomicity** — writes go to ``<path>.tmp.<pid>`` then ``fsync`` +
-    ``os.replace`` (the utils/locks.py rename discipline): a reader never
+    ``os.replace``: a reader never
     observes a torn file, a crash mid-write leaves the previous checkpoint
     intact.
   * **Fingerprint** — a JSON-serializable dict identifying the run

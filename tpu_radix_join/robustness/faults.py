@@ -45,13 +45,14 @@ STREAM_CORRUPT = "stream.corrupt_lane"         # sentinel-damaged key lane
 EXCHANGE_CORRUPT = "exchange.corrupt_lane"     # bit-flipped key post-exchange
 CKPT_SAVE = "checkpoint.save"                  # checkpoint write I/O error
 CKPT_LOAD = "checkpoint.load"                  # checkpoint read I/O error
-BACKEND_DISPATCH = "backend.dispatch"          # per-query tunnel outage
+BACKEND_DISPATCH = "backend.dispatch"          # per-query backend dispatch
+                                               # error
                                                # (service/session.py probe)
 BACKEND_STALL = "backend.stall"                # simulated hung collective:
                                                # the engine spins (checking
                                                # its cancel hook) instead of
                                                # raising — the watchdog's
-                                               # downed-tunnel failure mode
+                                               # hung-collective failure mode
                                                # (operators/hash_join.py)
 RANK_DEATH = "membership.rank_death"           # peer rank dies mid-run: its
                                                # lease lapses and the local
@@ -106,7 +107,7 @@ class InjectedKill(InjectedFault):
 
 
 class TransientFault(InjectedFault):
-    """Simulated transient error (tunnel hiccup): safe to retry.  Carries
+    """Simulated transient backend error: safe to retry.  Carries
     the transient infrastructure class so the shared retryability
     predicate (retry.is_retryable_class) and the service's circuit
     breaker classify it without type-sniffing."""

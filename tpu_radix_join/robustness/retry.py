@@ -35,11 +35,11 @@ and surfaced by main.py / bench reports):
     run configuration.
   * ``retries_exhausted``    — a retryable class persisted through every
     attempt (possibly after a failed fallback).
-  * ``backend_unavailable``  — the requested JAX backend never became
-    reachable within the wait budget (bench.py's pre-flight, the
-    service's per-query dispatch probe); distinct from
-    ``device_unavailable`` (init *failed*) because the remedy is
-    "retry later / check the tunnel", not "fall back to CPU".
+  * ``backend_unavailable``  — the device backend failed or stopped
+    answering a dispatch (a hung collective, a dispatch error, an open
+    circuit breaker); distinct from ``device_unavailable`` (init
+    *failed*) because the remedy is "retry later", not "fall back to
+    CPU".
   * ``admission_rejected``   — the resident service refused the query at
     the door (queue depth or per-tenant quota, service/admission.py).
     The query never ran; resubmitting later is safe by construction.
@@ -112,8 +112,8 @@ def classify_diagnostics(diag: dict) -> str:
 #: classes a same-config rerun can plausibly fix.  Two families:
 #:   * sizing — regrow-and-rerun repairs it (the engine's capacity loop);
 #:   * transient infrastructure — nothing is wrong with the query, the
-#:     substrate hiccupped (grid ``TransientFault`` pairs, a probe-phase
-#:     tunnel outage): re-dispatch later on the same shapes.
+#:     substrate hiccupped (grid ``TransientFault`` pairs, a backend
+#:     dispatch error): re-dispatch later on the same shapes.
 #: Everything else (key contracts, conservation, corruption, admission /
 #: deadline verdicts) is fatal for the attempt: retrying cannot fix data,
 #: and retrying a rejected or expired query would double-bill its tenant.
@@ -129,7 +129,7 @@ def is_retryable_class(failure_class: str,
     dispatch path.  Without a policy the :data:`DEFAULT_RETRYABLE` set
     applies; a :class:`RetryPolicy` narrows or widens it through its
     ``retryable_classes`` field (e.g. the engine's regrow loop passes a
-    sizing-only policy — a tunnel outage must fall through to the breaker,
+    sizing-only policy — a backend outage must fall through to the breaker,
     not spin the capacity doubler)."""
     classes = policy.retryable_classes if policy is not None \
         else DEFAULT_RETRYABLE
