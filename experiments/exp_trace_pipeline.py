@@ -2,7 +2,7 @@
 
 Produces the round-3 verdict's missing evidence (weak #2's last link): a
 real-chip ``jax.profiler`` trace of the fused 16M ⋈ 16M pipeline parsed into
-a per-op time breakdown (performance/trace.py), answering directly what
+a per-op time breakdown (``Measurements.trace``), answering directly what
 fraction of the pipeline is the sort — PERF_NOTES' sort-floor argument
 predicts >= ~95%.
 

@@ -81,3 +81,39 @@ def test_merge_scan_wide_compiles(one_chip):
     n = 2 * N // TILE * TILE
     _compile(lambda lo, hi, tag: merge_scan_partitions_wide(
         lo, hi, tag, num_partitions=32), one_chip, n, n, n)
+
+
+def test_fused_pipeline_names_its_stages(topo, monkeypatch):
+    """The one-node fused join at the real tuple width (uint32 key and rid
+    lanes), compiled for the described chip with ``auto`` choosing the
+    kernels as a TPU backend does: ``trj.sort`` owns the radix passes."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_radix_join import HashJoin, JoinConfig
+    from tpu_radix_join.data.tuples import TupleBatch
+    from tpu_radix_join.observability import stages
+    from tpu_radix_join.ops import sorting
+    from tpu_radix_join.ops.pallas import merge_scan
+
+    monkeypatch.setattr(sorting, "pallas_available", lambda: True)
+    monkeypatch.setattr(merge_scan, "pallas_available", lambda: True)
+    cfg = JoinConfig(num_nodes=1)
+    mesh = Mesh(np.array(topo.devices[:1]), (cfg.mesh_axis,))
+    engine = HashJoin(cfg, mesh=mesh)
+    n = 1 << 20
+    lane = jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=NamedSharding(
+        mesh, P(cfg.mesh_axis)))
+    batch = TupleBatch(key=lane, rid=lane)
+    compiled = engine._pipeline_fn(n, n, 8, 8).lower(batch, batch).compile()
+    program = stages.program_stages(compiled.as_text())
+    assert program.module.startswith("jit_trj_join")
+    passes = [name for name, op in program.opcodes.items()
+              if op == "custom-call"
+              and name.startswith("radix_pass_slots_pallas")]
+    assert len(passes) == 4
+    assert {program.stages[p] for p in passes} == {stages.SORT}
+    scans = [name for name, op in program.opcodes.items()
+             if op == "custom-call" and name.startswith("merge_scan")]
+    assert scans and {program.stages[s] for s in scans} == {
+        stages.MERGE_SCAN}

@@ -84,6 +84,8 @@ def test_cli_trace_records_ctotal(tmp_path, capsys):
     busiest timeline is a real device plane — the CTOTAL tag in .perf."""
     import json
 
+    import pytest
+
     out_dir = tmp_path / "exp"
     rc = main(["--tuples-per-node", "2048", "--nodes", "1",
                "--trace", "--output-dir", str(out_dir)])
@@ -91,9 +93,12 @@ def test_cli_trace_records_ctotal(tmp_path, capsys):
     info = json.loads((out_dir / "0.info").read_text())
     assert "trace" in info and info["trace"]["ops"], "per-op table missing"
     perf = (out_dir / "0.perf").read_text()
-    from tpu_radix_join.performance.trace import _is_device_plane
-    if _is_device_plane(info["trace"]["plane"]):   # CPU planes carry no
-        assert "CTOTAL" in perf                    # cycles analog (trace.py)
+    from tpu_radix_join.performance.measurements import DEVICE_PLANE
+    if info["trace"]["plane"].startswith(DEVICE_PLANE):   # a host plane
+        assert "CTOTAL" in perf        # carries no cycles analog (op_table)
+    # the op table is grouped by the join's stages
+    assert sum(info["trace"]["stages"].values()) == pytest.approx(
+        info["trace"]["busy_us"])
 
 
 def test_cli_trace_requires_output_dir(capsys):

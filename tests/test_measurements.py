@@ -281,35 +281,44 @@ def test_profiler_trace_smoke(tmp_path):
 
 
 def test_trace_parser_roundtrip_against_tf_proto(tmp_path):
-    """The hand-rolled xplane wire decoder must agree with the canonical
-    generated protobuf (tensorflow.tsl) on a real trace artifact — guards
-    the hardcoded field numbers."""
+    """The op table read through ``jax.profiler.ProfileData`` must agree
+    with the canonical generated protobuf (tensorflow.tsl) on a real trace
+    artifact: the same ops with the same summed durations."""
     import glob
     import jax.numpy as jnp
+
+    from tpu_radix_join.performance.measurements import (DEVICE_PLANE,
+                                                         HOST_XLA_LINE,
+                                                         OPS_LINE, op_table)
     m = Measurements()
     with m.trace(str(tmp_path), record=False):
         jnp.sort(jnp.arange(1 << 14, dtype=jnp.uint32)).block_until_ready()
     pb2 = pytest.importorskip("tensorflow.tsl.profiler.protobuf.xplane_pb2")
-    from tpu_radix_join.performance.trace import parse_xspace
     path = glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)[0]
-    data = open(path, "rb").read()
-    want = pb2.XSpace.FromString(data)
-    got = parse_xspace(data)
-    assert len(got) == len(want.planes)
-    want_by_name = {p.name: p for p in want.planes}
-    for gp in got:
-        wp = want_by_name[gp["name"]]
-        assert {i: n.display_name or n.name
-                for i, n in wp.event_metadata.items()} == gp["metadata"]
-        want_lines = {(ln.display_name or ln.name): ln for ln in wp.lines}
-        for line_name, per_md in gp["lines"]:
-            wl = want_lines[line_name]
-            want_per_md = {}
-            for ev in wl.events:
-                acc = want_per_md.setdefault(ev.metadata_id, [0, 0])
-                acc[0] += ev.duration_ps
-                acc[1] += max(1, ev.num_occurrences)
-            assert want_per_md == per_md, line_name
+    want = pb2.XSpace.FromString(open(path, "rb").read())
+    table = op_table(str(tmp_path))
+    assert table is not None
+    plane, = [p for p in want.planes if p.name == table["plane"]]
+    device = plane.name.startswith(DEVICE_PLANE)
+    want_ops = {}
+    for line in plane.lines:
+        name = line.display_name or line.name
+        if not (name == OPS_LINE if device
+                else name.startswith(HOST_XLA_LINE)):
+            continue
+        for ev in line.events:
+            md = plane.event_metadata[ev.metadata_id]
+            op = (md.display_name or md.name).partition(" = ")[0]
+            if "::" in op or op.startswith("end: "):
+                continue
+            acc = want_ops.setdefault(op.lstrip("%"), [0.0, 0])
+            acc[0] += ev.duration_ps / 1e6
+            acc[1] += 1
+    assert want_ops
+    assert {op: v["count"] for op, v in table["ops"].items()} == {
+        op: n for op, (_, n) in want_ops.items()}
+    for op, (us, _) in want_ops.items():
+        assert table["ops"][op]["us"] == pytest.approx(us)
 
 
 def test_slim_meta_preserves_failure_class_and_events_count():

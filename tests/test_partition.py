@@ -377,15 +377,26 @@ def test_auto_fallback_ticks_counter_and_logs_once(monkeypatch, capsys):
 
 
 def test_pallas_path_ticks_partpass_span():
+    """The fused pass ticks PARTPASS as it is traced, and its device work
+    is named ``trj.partition`` (where the trace-time span used to be)."""
+    import jax
+
+    from tpu_radix_join.observability import stages
+
     m = Measurements()
     radix.install_partition_observer(m)
     try:
         batch, dest, _, _ = _rand(512, 4, seed=12)
-        scatter_to_blocks(batch, dest, 4, 256, "inner", impl=INTERP)
+        compiled = jax.jit(lambda b, d: scatter_to_blocks(
+            b, d, 4, 256, "inner", impl=INTERP)).lower(batch, dest).compile()
         assert m.counters[PARTPASS] == 1
-        spans = [r for r in m.flightrec.records()
-                 if r["name"] == "partition_pass" and r["kind"] == "span"]
-        assert spans and spans[0]["impl"] == INTERP
+        program = stages.program_stages(compiled.as_text())
+        # every instruction that does device work is partition work
+        assert {st for name, st in program.stages.items()
+                if program.opcodes[name] not in stages.TRIVIAL} == {
+                    stages.PARTITION}
+        assert not [r for r in m.flightrec.records()
+                    if r["name"] == "partition_pass"]
     finally:
         radix.install_partition_observer(None)
 
@@ -441,14 +452,20 @@ def _oracle_join(**cfg_kw):
     return m
 
 
-def test_join_fused_partition_flat_mesh_oracle_exact():
+def test_join_fused_partition_flat_mesh_oracle_exact(monkeypatch):
+    from tpu_radix_join.observability import stages
+
+    tables = []
+    monkeypatch.setattr(stages, "record", lambda compiled: tables.append(
+        stages.program_stages(compiled.as_text())) or True)
     m = _oracle_join(partition_impl=INTERP, exchange_codec="pack")
-    assert m.counters[PARTPASS] > 0
     # any PARTFALLBACK here is the histogram auto-select degrading on the
     # CPU backend; the forced scatter impl itself never falls back
-    spans = [r for r in m.flightrec.records()
-             if r["name"] == "partition_pass" and r["kind"] == "span"]
-    assert spans and all(s["impl"] == INTERP for s in spans)
+    assert m.counters[PARTPASS] > 0
+    join, = [p for p in tables if p.module.startswith("jit_trj_join")]
+    assert [n for n, st in join.stages.items()
+            if st is None and join.opcodes[n] not in stages.TRIVIAL] == []
+    assert {stages.PARTITION, stages.EXCHANGE} <= set(join.stages.values())
 
 
 def test_join_fused_partition_hierarchical_mesh_oracle_exact():

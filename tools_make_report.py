@@ -85,7 +85,7 @@ def perf_row(d):
 
 def emit_profile(base_dir: str, out_path: str, name: str = None) -> int:
     """Distill one round's chip artifacts into a planner device profile."""
-    from tpu_radix_join.performance.trace import _is_device_plane
+    from tpu_radix_join.performance.measurements import DEVICE_PLANE
     from tpu_radix_join.planner.profile import (SORT_REF_ELEMS, load_profile,
                                                 sort_stage_units)
 
@@ -120,7 +120,7 @@ def emit_profile(base_dir: str, out_path: str, name: str = None) -> int:
             continue
         if (bd.get("sort_share") and bd.get("size")
                 and bd.get("discipline", "sort") == "sort"
-                and _is_device_plane(bd.get("plane", ""))):
+                and bd.get("plane", "").startswith(DEVICE_PLANE)):
             union = 2 * int(bd["size"])
             t_sort = bd["busy_us"] * bd["sort_share"] / bd["iters"] / 1e3
             unit = t_sort / ((union / SORT_REF_ELEMS)
@@ -149,7 +149,7 @@ def emit_timeline(base_dir: str, out_path: str) -> int:
     """Merge per-rank span files under ``base_dir`` into one Chrome trace."""
     from tpu_radix_join.observability.timeline import merge_timeline
 
-    doc = merge_timeline(base_dir, out_path=out_path, trace_dir=base_dir)
+    doc = merge_timeline(base_dir, out_path=out_path)
     if doc is None:
         print(f"ERROR: no *.spans.json under {base_dir} — run the driver "
               f"with --timeline-dir first", file=sys.stderr)

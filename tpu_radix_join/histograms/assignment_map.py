@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tpu_radix_join.observability import stages
+
 
 def round_robin_assignment(num_partitions: int, num_nodes: int) -> jnp.ndarray:
     """assignment[p] = p % numberOfNodes (AssignmentMap.cpp:41-43)."""
@@ -53,6 +55,7 @@ def load_aware_assignment(
     return assignment
 
 
+@jax.named_scope(stages.PARTITION)
 def compute_partition_assignment(
     inner_global_hist: jnp.ndarray,
     outer_global_hist: jnp.ndarray,

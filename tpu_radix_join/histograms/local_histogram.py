@@ -7,12 +7,15 @@ counting tuples per network partition, radix = low
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from tpu_radix_join.data.tuples import TupleBatch, partition_ids
+from tpu_radix_join.observability import stages
 from tpu_radix_join.ops.radix import local_histogram
 
 
+@jax.named_scope(stages.PARTITION)
 def compute_local_histogram(batch: TupleBatch, fanout_bits: int,
                             valid: jnp.ndarray | None = None):
     """Returns (pid uint32 [n], histogram uint32 [1 << fanout_bits])."""

@@ -25,6 +25,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from tpu_radix_join.observability import stages
 from tpu_radix_join.parallel.mesh import AxisName
 
 
@@ -35,6 +36,7 @@ class Offsets(NamedTuple):
     all_local_hists: jnp.ndarray  # uint32 [N, P] gathered local histograms
 
 
+@jax.named_scope(stages.PARTITION)
 def compute_offsets(
     local_hist: jnp.ndarray,
     global_hist: jnp.ndarray,

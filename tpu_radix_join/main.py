@@ -1475,8 +1475,7 @@ def main(argv=None) -> int:
         if tracer is not None:
             # save in the finally: a failed/degraded run's timeline is the
             # one a post-mortem needs most
-            path = tracer.save(args.timeline_dir,
-                               device_summary=meas.meta.get("trace"))
+            path = tracer.save(args.timeline_dir)
             print(f"[OBS] timeline spans stored {path}", file=sys.stderr)
     if distributed and membership is not None and membership.lost:
         # a survivor of a rank loss must NOT walk jax.distributed's atexit

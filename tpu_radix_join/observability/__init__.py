@@ -12,6 +12,9 @@ Three pillars over the ``performance`` registry (ISSUE 3):
 
 Merging per-rank span files onto one aligned clock lives in
 :mod:`timeline` (driven by ``tools_make_report.py --emit-timeline``).
+:mod:`stages` names the join's device work: one ``trj.*`` named scope
+per stage, and per process the table of which stage owns each compiled
+instruction, read once per compile.
 
 The cross-run memory layer (ISSUE 9) adds two:
 

@@ -30,7 +30,9 @@ from __future__ import annotations
 
 from typing import List
 
-from tpu_radix_join.performance.measurements import COMPILEMS, NCOMPILE
+# the module, not its names: measurements imports this package's stage
+# names, so either may be the first of the two to load
+from tpu_radix_join.performance import measurements as _tags
 
 #: the duration event XLA fires once per backend compile (jax 0.4.x)
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -45,8 +47,8 @@ def _on_duration(event: str, duration_secs: float, **kw) -> None:
     ms = max(0, int(round(duration_secs * 1e3)))
     for m in list(_active):
         try:
-            m.incr(NCOMPILE)
-            m.incr(COMPILEMS, by=ms)
+            m.incr(_tags.NCOMPILE)
+            m.incr(_tags.COMPILEMS, by=ms)
         except Exception:   # noqa: BLE001 — telemetry must not fail a compile
             pass
 

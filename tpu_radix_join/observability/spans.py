@@ -7,8 +7,7 @@ watch a multi-hour grid join in flight.  This module records the same tag
 vocabulary as intervals on a wall-clock-anchored timeline and exports them
 per rank in Chrome trace-event JSON (the format Perfetto / ``chrome://
 tracing`` load natively), so host phases, robustness instant events
-(fault/retry/checkpoint), planner decisions, and the xplane per-op device
-summary all land in ONE view.
+(fault/retry/checkpoint) and planner decisions land in ONE view.
 
 Clock discipline: each tracer pins a wall-clock epoch anchor
 (``epoch_s = time.time()``) and a monotonic anchor (``time.perf_counter()``)
@@ -34,10 +33,8 @@ import os
 import time
 from typing import Dict, List, Optional
 
-# Perfetto track layout: one process per rank, host phases on tid 0,
-# the synthetic device-op summary track (timeline.py) on tid 1.
+# Perfetto track layout: one process per rank, host phases on tid 0.
 HOST_TID = 0
-DEVICE_TID = 1
 
 SPAN_SUFFIX = ".spans.json"
 
@@ -149,22 +146,13 @@ class SpanTracer:
             },
         }
 
-    def save(self, out_dir: str, device_summary: Optional[dict] = None,
-             filename: Optional[str] = None) -> str:
+    def save(self, out_dir: str, filename: Optional[str] = None) -> str:
         """Write ``<rank>.spans.json``; any still-open spans are closed at
-        now (a crash-path save must not lose the run's outermost span).
-
-        ``device_summary`` (the xplane per-op breakdown from
-        performance/trace.summarize_trace, i.e. ``meta["trace"]``) is
-        embedded in the metadata so the merger can graft a device track
-        next to this rank's host phases without re-parsing the xplane.
-        """
+        now (a crash-path save must not lose the run's outermost span)."""
         for name in [n for n, stack in self._open.items() if stack]:
             while self._open[name]:
                 self.end(name, unclosed=True)
         doc = self.to_chrome()
-        if device_summary is not None:
-            doc["metadata"]["device_summary"] = device_summary
         os.makedirs(out_dir, exist_ok=True)
         path = os.path.join(out_dir,
                             filename or f"{self.rank}{SPAN_SUFFIX}")

@@ -2,18 +2,12 @@
 
 Each rank's ``<rank>.spans.json`` (observability/spans.py) carries event
 timestamps relative to that rank's own wall-clock epoch anchor.  The merge
-shifts every rank onto the earliest anchor's clock — host phase spans,
-robustness instant events, and the grafted device-op track from any rank
-then share one timeline a single Perfetto load can scrub across ranks
-(the cross-rank view the reference's per-rank ``.perf`` scalars never had).
-
-Device track: when a rank's span file embeds an xplane per-op summary
-(``--trace`` runs: performance/trace.summarize_trace via
-``meta["trace"]``), its ops are laid out as a synthetic sequential track
-(tid 1) under that rank — total durations are real, op order and start
-offsets are a summary layout, which each event's ``args`` say out loud.
-Without embedded summaries the merger scans the input dir for raw
-``*.xplane.pb`` artifacts as a fallback.
+shifts every rank onto the earliest anchor's clock — host phase spans
+and robustness instant events from every rank then share one timeline a
+single Perfetto load can scrub across ranks (the cross-rank view the
+reference's per-rank ``.perf`` scalars never had).  Device operations are
+not here: they live in the profiler's own trace (``--trace``), where the
+program's ``trj.*`` host spans share their clock.
 """
 
 from __future__ import annotations
@@ -23,11 +17,7 @@ import json
 import os
 from typing import List, Optional, Tuple
 
-from tpu_radix_join.observability.spans import (DEVICE_TID, SPAN_SUFFIX)
-
-# cap the synthetic device track: a full xplane op table can run to
-# thousands of rows, and the graft is a summary view, not a dump
-DEVICE_TRACK_MAX_OPS = 64
+from tpu_radix_join.observability.spans import SPAN_SUFFIX
 
 
 def find_span_files(timeline_dir: str) -> List[str]:
@@ -52,44 +42,12 @@ def _load(path: str) -> Tuple[Optional[dict], Optional[str]]:
     return doc, None
 
 
-def _device_track_events(rank: int, summary: dict, start_us: float,
-                         source: str) -> List[dict]:
-    """Synthetic sequential layout of a per-op device summary."""
-    events = [{
-        "name": "thread_name", "ph": "M", "pid": rank, "tid": DEVICE_TID,
-        "args": {"name": f"device ops (summary: {summary.get('plane', '?')})"},
-    }]
-    t = start_us
-    ops = sorted(summary.get("ops", {}).items(),
-                 key=lambda kv: -kv[1]["us"])
-    for name, v in ops[:DEVICE_TRACK_MAX_OPS]:
-        events.append({
-            "name": name, "ph": "X", "ts": t, "dur": max(0.0, v["us"]),
-            "pid": rank, "tid": DEVICE_TID,
-            "args": {"count": v.get("count", 1), "source": source,
-                     "layout": "sequential summary (durations real, "
-                               "offsets synthetic)"},
-        })
-        t += max(0.0, v["us"])
-    if len(ops) > DEVICE_TRACK_MAX_OPS:
-        rest = sum(v["us"] for _, v in ops[DEVICE_TRACK_MAX_OPS:])
-        events.append({
-            "name": f"... {len(ops) - DEVICE_TRACK_MAX_OPS} more ops",
-            "ph": "X", "ts": t, "dur": max(0.0, rest),
-            "pid": rank, "tid": DEVICE_TID,
-            "args": {"source": source, "layout": "tail aggregate"},
-        })
-    return events
-
-
-def merge_timeline(timeline_dir: str, out_path: Optional[str] = None,
-                   trace_dir: Optional[str] = None) -> Optional[dict]:
+def merge_timeline(timeline_dir: str,
+                   out_path: Optional[str] = None) -> Optional[dict]:
     """Merge every ``*.spans.json`` under ``timeline_dir``.
 
     Returns the merged Chrome-trace object (written to ``out_path`` when
-    given), or None when the directory holds no span files.  ``trace_dir``
-    (default: ``timeline_dir`` itself) is scanned for xplane artifacts only
-    for ranks whose span files embed no device summary.
+    given), or None when the directory holds no span files.
 
     Partial-tolerant by design: a rank killed mid-run (watchdog, SIGKILL)
     leaves a truncated or absent span file, and the surviving ranks'
@@ -121,8 +79,6 @@ def merge_timeline(timeline_dir: str, out_path: Optional[str] = None,
 
     merged: List[dict] = []
     ranks = {}
-    any_device_summary = False
-    min_host_ts = {}
     for (path, doc), epoch_s in zip(docs, anchors):
         md = doc.get("metadata", {})
         rank = int(md.get("rank", 0))
@@ -138,32 +94,7 @@ def merge_timeline(timeline_dir: str, out_path: Optional[str] = None,
             ev = dict(ev)
             if "ts" in ev:
                 ev["ts"] = float(ev["ts"]) + shift_us
-                key = ev.get("pid", rank)
-                if ev.get("ph") == "X":
-                    min_host_ts[key] = min(min_host_ts.get(key, ev["ts"]),
-                                           ev["ts"])
             merged.append(ev)
-        summary = md.get("device_summary")
-        if summary:
-            any_device_summary = True
-            merged.extend(_device_track_events(
-                rank, summary, min_host_ts.get(rank, shift_us),
-                source=f"{os.path.basename(path)}:metadata.device_summary"))
-
-    if not any_device_summary:
-        # fallback: raw xplane artifacts next to the span files (a --trace
-        # run whose spans predate the embedded-summary save path)
-        from tpu_radix_join.performance.trace import summarize_trace
-        scan = trace_dir or timeline_dir
-        try:
-            summary = summarize_trace(scan)
-        except Exception:
-            summary = None
-        if summary:
-            rank0 = min(ranks)
-            merged.extend(_device_track_events(
-                rank0, summary, min_host_ts.get(rank0, 0.0),
-                source=f"xplane scan of {scan}"))
 
     # expected world size: the largest ``nodes`` tag any rank declared
     # (Measurements.attach_tracer stamps it); 0 when no rank carried one

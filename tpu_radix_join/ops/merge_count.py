@@ -28,6 +28,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from tpu_radix_join.observability import stages
+
 # Largest valid key for the merge path (inclusive): 31-bit packing with two
 # reserved pad key slots (0x7FFFFFFE, 0x7FFFFFFF) above it.  The pipeline's
 # keys_ok check enforces key <= MAX_MERGE_KEY; violations are routed to the
@@ -104,6 +106,7 @@ def presort_keys(keys: jnp.ndarray) -> jnp.ndarray:
     return _sort_unstable(keys)
 
 
+@jax.named_scope(stages.MERGE_SCAN)
 def merge_count_presorted(r_sorted: jnp.ndarray, s_keys: jnp.ndarray,
                           return_max_weight: bool = False):
     """Duplicate-aware match count of ``s_keys`` against an ALREADY-SORTED
@@ -134,6 +137,7 @@ def merge_count_presorted(r_sorted: jnp.ndarray, s_keys: jnp.ndarray,
     return total
 
 
+@jax.named_scope(stages.MERGE_SCAN)
 def merge_count_chunks(r_keys: jnp.ndarray, s_keys: jnp.ndarray,
                        num_chunks: int = 4096,
                        return_max_weight: bool = False):
@@ -157,6 +161,7 @@ def merge_count_chunks(r_keys: jnp.ndarray, s_keys: jnp.ndarray,
     return counts
 
 
+@jax.named_scope(stages.MERGE_SCAN)
 def merge_count_pallas(r_keys: jnp.ndarray, s_keys: jnp.ndarray,
                        interpret: bool = False) -> jnp.ndarray:
     """Match counting with the fused Pallas scan kernel for the post-sort
@@ -211,6 +216,7 @@ def _pack_pm(r_keys: jnp.ndarray, s_keys: jnp.ndarray,
     ])
 
 
+@jax.named_scope(stages.MERGE_SCAN)
 def merge_count_per_partition(r_keys: jnp.ndarray, s_keys: jnp.ndarray,
                               fanout_bits: int,
                               impl: str | None = None,
@@ -262,6 +268,7 @@ def merge_count_per_partition(r_keys: jnp.ndarray, s_keys: jnp.ndarray,
     return counts
 
 
+@jax.named_scope(stages.MERGE_SCAN)
 def merge_count_per_partition_full(r_keys: jnp.ndarray, s_keys: jnp.ndarray,
                                    fanout_bits: int,
                                    impl: str | None = None,
@@ -357,6 +364,7 @@ def _rotate_pid(lo: jnp.ndarray, fanout_bits: int) -> jnp.ndarray:
     return (lo << jnp.uint32(32 - fanout_bits)) | (lo >> f)
 
 
+@jax.named_scope(stages.MERGE_SCAN)
 def merge_count_wide_per_partition(
     r_lo: jnp.ndarray, r_hi: jnp.ndarray,
     s_lo: jnp.ndarray, s_hi: jnp.ndarray,

@@ -15,13 +15,13 @@ pass), i.e. the TPU equivalents of the GPU ``histogram_build_L1/L2`` +
 from __future__ import annotations
 
 import sys
-from contextlib import nullcontext
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from tpu_radix_join.data.tuples import CompressedBatch, make_padding_like
+from tpu_radix_join.observability import stages
 from tpu_radix_join.ops.sorting import sort_kv_unstable
 from tpu_radix_join.performance.measurements import PARTFALLBACK, PARTPASS
 
@@ -40,21 +40,19 @@ _fallback_logged = False
 
 def install_partition_observer(measurements) -> None:
     """Register a performance.Measurements (or None) to receive PARTPASS /
-    PARTFALLBACK ticks and partition spans from trace-time impl selection.
-    Process-global: the most recent engine wins, which is the engine whose
-    programs are being traced."""
+    PARTFALLBACK ticks from trace-time impl selection.  Process-global: the
+    most recent engine wins, which is the engine whose programs are being
+    traced."""
     _partition_observer["meas"] = measurements
 
 
-def _partition_span(impl: str, site: str, num_partitions: int):
-    """Span bracketing the trace-time construction of one fused partition
-    op — mirrored into the flight recorder ring like every span."""
+def _partition_pass():
+    """Tick PARTPASS for one fused partition op as it is traced, and
+    return the ``trj.partition`` scope that names its device work."""
     m = _partition_observer["meas"]
-    if m is None:
-        return nullcontext()
-    m.incr(PARTPASS)
-    return m.span("partition_pass", impl=impl, site=site,
-                  num_partitions=num_partitions)
+    if m is not None:
+        m.incr(PARTPASS)
+    return jax.named_scope(stages.PARTITION)
 
 
 def _note_fallback(site: str, num_partitions: int, why: str) -> None:
@@ -99,6 +97,7 @@ def resolve_partition_impl(impl: str | None, num_partitions: int,
     return impl
 
 
+@jax.named_scope(stages.PARTITION)
 def local_histogram(pid: jnp.ndarray, num_partitions: int,
                     valid: jnp.ndarray | None = None,
                     impl: str | None = None) -> jnp.ndarray:
@@ -146,6 +145,7 @@ def exclusive_cumsum(hist: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([jnp.zeros((1,), hist.dtype), jnp.cumsum(hist)[:-1]])
 
 
+@jax.named_scope(stages.PARTITION)
 def reorder_by_partition(
     batch: CompressedBatch, pid: jnp.ndarray, num_partitions: int,
     valid: jnp.ndarray | None = None,
@@ -172,7 +172,7 @@ def reorder_by_partition(
     impl = resolve_partition_impl(impl, num_partitions, "reorder_by_partition")
     if impl in ("pallas", "pallas_interpret"):
         from tpu_radix_join.ops.pallas.partition import partition_slots_pallas
-        with _partition_span(impl, "reorder_by_partition", num_partitions):
+        with _partition_pass():
             # num_partitions + 1 dense groups: the virtual invalid partition
             # is a REAL group here so every tuple lands (a permutation), with
             # invalid rows contiguous at the tail exactly like the sort path
@@ -207,6 +207,7 @@ def reorder_by_partition(
     return out, sorted_lanes[-1], hist, exclusive_cumsum(hist)
 
 
+@jax.named_scope(stages.PARTITION)
 def scatter_to_blocks(
     batch,
     dest: jnp.ndarray,
@@ -271,6 +272,7 @@ def scatter_to_blocks(
     return blocks, counts, overflow
 
 
+@jax.named_scope(stages.PARTITION)
 def scatter_to_blocks_grouped(
     batch,
     dest: jnp.ndarray,
@@ -410,7 +412,7 @@ def _scatter_blocks_fused(batch, dest, sub, num_blocks, num_sub, capacity,
     num_groups = num_blocks * num_sub
     if valid is not None:
         key = jnp.where(valid, key, jnp.uint32(num_groups))
-    with _partition_span(impl, "scatter_to_blocks", num_groups):
+    with _partition_pass():
         slots, ghist = partition_slots_pallas(
             key, num_groups=num_groups, group_size=num_sub,
             capacity=capacity, interpret=(impl == "pallas_interpret"))

@@ -38,6 +38,7 @@ from contextlib import nullcontext
 import jax
 import jax.numpy as jnp
 
+from tpu_radix_join.observability import stages
 from tpu_radix_join.ops.pallas.merge_scan import pallas_available
 from tpu_radix_join.ops.pallas.radix_sort import radix_sort_pallas
 from tpu_radix_join.performance.measurements import SORTFALLBACK, SORTPASS
@@ -154,6 +155,7 @@ def resolve_sort_impl(impl: str | None, elems: int, site: str,
     return impl
 
 
+@jax.named_scope(stages.SORT)
 def sort_unstable(x: jnp.ndarray, dimension: int = -1, *,
                   impl: str | None = None,
                   key_bound: int | None = None) -> jnp.ndarray:
@@ -168,6 +170,7 @@ def sort_unstable(x: jnp.ndarray, dimension: int = -1, *,
     return jax.lax.sort([x], dimension=dimension, is_stable=False)[0]
 
 
+@jax.named_scope(stages.SORT)
 def sort_kv_unstable(key: jnp.ndarray, *values: jnp.ndarray,
                      impl: str | None = None, key_bound: int | None = None):
     """Unstable key-value sort; returns (sorted key, *values in key order)."""
@@ -181,6 +184,7 @@ def sort_kv_unstable(key: jnp.ndarray, *values: jnp.ndarray,
     return jax.lax.sort((key, *values), num_keys=1, is_stable=False)
 
 
+@jax.named_scope(stages.SORT)
 def sort_lex_unstable(*operands: jnp.ndarray, num_keys: int,
                       dimension: int = -1, impl: str | None = None,
                       key_bounds=None):

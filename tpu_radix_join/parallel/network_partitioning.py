@@ -23,9 +23,11 @@ from __future__ import annotations
 
 from typing import NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from tpu_radix_join.data.tuples import TupleBatch, partition_ids, valid_mask
+from tpu_radix_join.observability import stages
 from tpu_radix_join.parallel.window import Window, ExchangeResult
 
 
@@ -37,6 +39,7 @@ class NetworkPartitionResult(NamedTuple):
     send_overflow: jnp.ndarray
 
 
+@jax.named_scope(stages.PARTITION)
 def network_partition(
     batch: TupleBatch,
     fanout_bits: int,

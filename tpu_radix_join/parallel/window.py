@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from tpu_radix_join.data.tuples import (WireSpec, make_wire_spec, pack_blocks,
                                         unpack_blocks)
+from tpu_radix_join.observability import stages
 from tpu_radix_join.ops.radix import (scatter_to_blocks,
                                       scatter_to_blocks_grouped)
 from tpu_radix_join.parallel.mesh import AxisName
@@ -71,6 +72,7 @@ def parse_exchange_mode(mode, block: int) -> int:
     return min(k, block) if block else k
 
 
+@jax.named_scope(stages.EXCHANGE)
 def block_all_to_all(x: jnp.ndarray, num_nodes: int, block: int,
                      axis_name: AxisName, mode="fused") -> jnp.ndarray:
     """Dense block exchange: slice ``x``'s leading [num_nodes * block] axis
@@ -93,13 +95,13 @@ def block_all_to_all(x: jnp.ndarray, num_nodes: int, block: int,
             f"num_nodes * block = {num_nodes} * {block} = "
             f"{num_nodes * block} (one fixed-capacity block per "
             f"destination)")
-    stages = parse_exchange_mode(mode, block)
-    if stages == 1:
+    n_stages = parse_exchange_mode(mode, block)
+    if n_stages == 1:
         return _one_exchange(x, num_nodes, block, axis_name)
     rest = x.shape[1:]
     v = x.reshape((num_nodes, block) + rest)
-    base, extra = divmod(block, stages)
-    sizes = [base + (1 if i < extra else 0) for i in range(stages)]
+    base, extra = divmod(block, n_stages)
+    sizes = [base + (1 if i < extra else 0) for i in range(n_stages)]
     outs = []
     prev = None
     off = 0
@@ -132,6 +134,7 @@ def _one_exchange(x: jnp.ndarray, num_nodes: int, block: int,
     ).reshape((num_nodes * block,) + x.shape[1:])
 
 
+@jax.named_scope(stages.EXCHANGE)
 def hierarchical_block_all_to_all(x: jnp.ndarray, num_nodes: int, block: int,
                                   dcn_axis: str, ici_axis: str) -> jnp.ndarray:
     """Two-stage exchange over a ``[num_hosts, per_host]`` mesh.
@@ -232,6 +235,7 @@ class Window:
                               key_bound=self.key_bound,
                               rid_bound=self.rid_bound)
 
+    @jax.named_scope(stages.EXCHANGE)
     def exchange(self, batch, dest: jnp.ndarray,
                  valid: jnp.ndarray | None = None,
                  pid: jnp.ndarray | None = None) -> ExchangeResult:
@@ -271,6 +275,7 @@ class Window:
         recv_counts = block_all_to_all(sent_counts, n, 1, self.axis_name)
         return ExchangeResult(received, recv_counts, overflow)
 
+    @jax.named_scope(stages.CHECKS)
     def diagnostics(
         self, result: ExchangeResult, global_hist: jnp.ndarray,
         assignment: jnp.ndarray,

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_radix_join.observability import stages
 from tpu_radix_join.ops.sorting import segmented_xor_fold
 from tpu_radix_join.robustness.retry import DATA_CORRUPTION
 
@@ -59,6 +60,7 @@ def checksum_rows(wide: bool) -> int:
     return 5 if wide else 3
 
 
+@jax.named_scope(stages.CHECKS)
 def device_partition_checksums(
     key: jnp.ndarray,
     pid: jnp.ndarray,
@@ -92,6 +94,7 @@ def device_partition_checksums(
     return adds, xors
 
 
+@jax.named_scope(stages.CHECKS)
 def global_partition_checksums(
     key: jnp.ndarray,
     pid: jnp.ndarray,
