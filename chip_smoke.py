@@ -219,8 +219,7 @@ def run(four_chips: bool) -> dict:
           flush=True)
     print(f"[SMOKE] compile cache {cache} ({entries} entries before run)",
           flush=True)
-    print(f"[SMOKE] auto sort impl at {2 * TUPLES_PER_NODE} elements: "
-          f"{resolve_sort_impl('auto', 2 * TUPLES_PER_NODE, 'chip_smoke')}",
+    print(f"[SMOKE] auto sort impl: {resolve_sort_impl('auto')}",
           flush=True)
 
     gs = TUPLES_PER_NODE * nodes

@@ -1,5 +1,5 @@
 """Pallas LSD radix sort (ops/pallas/radix_sort.py) and its wiring
-(ops/sorting impl switch, planner sort arm, fallback telemetry).
+(ops/sorting impl switch, planner sort arm, radix-sort telemetry).
 
 Parity contract with lax.sort: keys come out non-decreasing and the
 (key, *values) row multiset is preserved.  Both engines are *unstable*
@@ -15,6 +15,7 @@ are kept to a handful of (n, shift) combos because each distinct combo
 costs a fresh trace of the pass kernel.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -204,31 +205,39 @@ def test_xor_fold_exact_under_forced_radix_arm():
     np.testing.assert_array_equal(got, expect)
 
 
-# ------------------------------------------------------- fallback telemetry
+# ------------------------------------------------------- auto is lax.sort
 
-def test_auto_fallback_ticks_counter_once_and_logs_once(monkeypatch, capsys):
+@pytest.mark.parametrize("elems", [1 << 10, 1 << 18, 1 << 25, 40_000_000])
+@pytest.mark.parametrize("shape", ["eligible", "batched", "int32"])
+def test_auto_resolves_to_xla_at_every_size(monkeypatch, capsys, elems,
+                                            shape):
+    # stand for a TPU backend: the radix kernel could compile, and auto
+    # still takes lax.sort, quietly, at every size and for every shape
+    from tpu_radix_join.ops.pallas import merge_scan
+    monkeypatch.setattr(merge_scan, "pallas_available", lambda: True)
     m = Measurements()
     sorting.install_sort_observer(m)
-    monkeypatch.setattr(sorting, "_fallback_logged", False)
-    monkeypatch.setattr(sorting, "_fallback_ticked", False)
+    eligible = shape == "eligible"
     try:
-        # structural ineligibility is quiet even under auto
-        assert resolve_sort_impl("auto", 1 << 20, "t", eligible=False) \
-            == "xla"
+        assert resolve_sort_impl("auto", eligible) == "xla"
+        assert resolve_sort_impl(None, eligible) == "xla"
+        # what traces: one lax.sort and no radix pass, with no allocation
+        dims = {"eligible": ((elems,), jnp.uint32),
+                "batched": ((8, elems // 8), jnp.uint32),
+                "int32": ((elems,), jnp.int32)}[shape]
+        x = jax.ShapeDtypeStruct(*dims)
+        jaxpr = str(jax.make_jaxpr(lambda k: sort_kv_unstable(k, k))(x))
+        assert "sort[" in jaxpr and "pallas_call" not in jaxpr
+        assert m.counters[SORTPASS] == 0
         assert m.counters[SORTFALLBACK] == 0
-        # CPU backend: auto degrades loudly — but the counter ticks ONCE
-        # per process, not once per sort site (the acceptance pin)
-        assert resolve_sort_impl("auto", 1 << 20, "site_a") == "xla"
-        assert resolve_sort_impl(None, 1 << 20, "site_b") == "xla"
-        err = capsys.readouterr().err
-        assert err.count("fell back to lax.sort") == 1
-        assert m.counters[SORTFALLBACK] == 1
-        # explicit impls never tick the fallback
-        assert resolve_sort_impl("xla", 1 << 20, "t") == "xla"
-        assert resolve_sort_impl(INTERP, 1 << 20, "t") == INTERP
-        assert m.counters[SORTFALLBACK] == 1
+        # forced arms are honoured wherever the kernel can express them
+        assert resolve_sort_impl("xla", eligible) == "xla"
+        for forced in ("pallas", INTERP):
+            assert resolve_sort_impl(forced, eligible) == (
+                forced if eligible else "xla")
     finally:
         sorting.install_sort_observer(None)
+    assert capsys.readouterr().err == ""
 
 
 def test_pallas_path_ticks_sortpass_span():
@@ -252,24 +261,24 @@ def test_plan_sort_prices_both_arms():
     from tpu_radix_join.planner.cost_model import plan_sort
     from tpu_radix_join.planner.profile import load_profile
     prof = load_profile()
-    on = plan_sort(prof, 1 << 25, pallas_ok=True)
-    off = plan_sort(prof, 1 << 25, pallas_ok=False)
-    assert off.impl == "xla" and on.pallas_ms == off.pallas_ms
-    assert on.sort_ms == min(on.pallas_ms, on.xla_ms)
+    plan = plan_sort(prof, 1 << 25)
+    # auto binds lax.sort, the arm the runtime's auto picks, while the
+    # radix arm stays priced for the explain table
+    assert plan.impl == "xla" and plan.sort_ms == plan.xla_ms
+    assert plan.pallas_ms > 0 and plan.passes == 4
     # a bound halves the radix arm's passes and its price with them
-    bounded = plan_sort(prof, 1 << 25, key_bound=1 << 16, pallas_ok=True)
-    assert bounded.passes == 2 < on.passes == 4
-    assert bounded.pallas_ms < on.pallas_ms
+    bounded = plan_sort(prof, 1 << 25, key_bound=1 << 16)
+    assert bounded.impl == "xla"
+    assert bounded.passes == 2 < plan.passes
+    assert bounded.pallas_ms < plan.pallas_ms
     # the radix arm prices off the schema-v5 constant
     bumped = prof.replace_constants(radix_sort_pass_unit_ms={
         "value": prof.value("radix_sort_pass_unit_ms") * 10,
         "source": "test"})
-    assert plan_sort(bumped, 1 << 25, pallas_ok=True).pallas_ms \
-        > on.pallas_ms
-    # below the runtime's size floor and on batched rows the plan stays
-    # xla, matching what trace-time selection would actually do
-    assert plan_sort(prof, 1 << 10, pallas_ok=True).impl == "xla"
-    assert plan_sort(prof, 1 << 25, rows=32, pallas_ok=True).impl == "xla"
+    assert plan_sort(bumped, 1 << 25).pallas_ms > plan.pallas_ms
+    # small and batched sorts stay xla too
+    assert plan_sort(prof, 1 << 10).impl == "xla"
+    assert plan_sort(prof, 1 << 25, rows=32).impl == "xla"
 
 
 def test_strategy_rows_carry_the_sort_arm():
@@ -293,7 +302,7 @@ def test_plan_binds_sort_impl_and_v4_plans_still_load():
                         Workload(r_tuples=1 << 22, s_tuples=1 << 22,
                                  num_nodes=8))
     assert PLAN_SCHEMA_VERSION == 5
-    assert plan.sort_impl in ("pallas", "xla")
+    assert plan.sort_impl == "xla"
     assert plan.config_kwargs()["sort_impl"] == plan.sort_impl
     doc = plan.to_dict()
     doc.pop("sort_impl")

@@ -76,10 +76,9 @@ host CPU).  ``sort_ms`` / ``sort_xla_ms`` / ``sort_kernel_ms`` /
 ms/Mtuple/digit-pass constant the profile fitter recovers, and
 ``sort_passes`` / ``sort_bounded_passes`` count LSD digit passes (more
 passes means the key-bound pass skip stopped firing) — all pinned
-lower-is-better, alongside the ``SORTFALLBACK`` counter (the sort
-auto-select degrading to lax.sort; it ticks at most once per process by
-design, so on a TPU backend any nonzero value means the Pallas sort
-engine stopped being selected).
+lower-is-better, alongside the ``SORTFALLBACK`` counter (it reads 0
+since the sort's ``auto`` is lax.sort itself, so any nonzero value is a
+regression).
 
 A ``--recovery-bench`` BENCH json gates the elastic-recovery A/B
 (robustness/membership.py + recovery.py — kill-1-of-8 partition-level

@@ -5,8 +5,9 @@ PR 12 centralised every hot reorder behind ``ops/sorting.py``
 the xla-vs-Pallas radix arm is one trace-time decision; PR 10 did the
 same for partitioning.  A direct ``jax.lax.sort`` / ``jnp.sort`` /
 ``jnp.argsort`` call anywhere else silently bypasses the switch: the
-site never sees the Pallas arm, never ticks SORTPASS/SORTFALLBACK, and
-the planner's ``plan_sort`` prediction stops matching what traces.
+site never sees the Pallas arm, never ticks SORTPASS, sits outside the
+``trj.sort`` scope that names the sort's device time, and the planner's
+``plan_sort`` prediction stops matching what traces.
 
 Host-side ``np.sort``/``np.argsort`` are NOT flagged — numpy on host
 arrays is the oracle/verification idiom, not a device sort.  The sort

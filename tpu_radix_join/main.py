@@ -94,14 +94,13 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["auto", "xla", "pallas", "pallas_interpret"],
                    default="auto",
                    help="sort implementation behind every hot reorder "
-                        "(ops/sorting.py): 'auto' takes the Pallas LSD "
-                        "radix sort (ops/pallas/radix_sort.py) on a TPU "
-                        "backend for large 1-D uint32 sorts — fewer digit "
-                        "passes when key bounds shrink the effective "
-                        "width — else lax.sort (the degrade ticks "
-                        "SORTFALLBACK once per process and logs once); "
-                        "'xla' forces lax.sort; 'pallas_interpret' runs "
-                        "the kernel interpreted (CPU parity/bench)")
+                        "(ops/sorting.py): 'auto' and 'xla' are lax.sort, "
+                        "which beats the radix sort at every size measured "
+                        "on a TPU v5e (a 20M x 20M join: 75 ms against "
+                        "2647 ms); 'pallas' forces the Pallas LSD radix "
+                        "sort (ops/pallas/radix_sort.py) for 1-D uint32 "
+                        "sorts; 'pallas_interpret' runs that kernel "
+                        "interpreted (CPU parity/bench)")
     p.add_argument("--cpu-fallback", action="store_true",
                    help="if device/mesh init fails, rebuild the engine over "
                         "host CPU devices (loud [DEGRADE] warning) instead "

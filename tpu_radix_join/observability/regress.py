@@ -107,11 +107,9 @@ _LOWER_BETTER_SUBSTRINGS = ("rejection_rate", "miss_rate", "degraded_rate",
                             # per-digit-pass unit, and the pass counts are
                             # all times or work counts (more LSD passes per
                             # sort means the key-bound pass skip stopped
-                            # firing); SORTFALLBACK counts the auto-select
-                            # degrading to lax.sort — it ticks once per
-                            # process by design, so on a TPU backend any
-                            # nonzero value means the Pallas sort engine
-                            # stopped being selected
+                            # firing); SORTFALLBACK reads 0 since ``auto``
+                            # is lax.sort itself, so any nonzero value is
+                            # a regression
                             "sort_ms", "sort_xla_ms", "sort_kernel_ms",
                             "sort_pass_unit_ms", "sort_passes",
                             "sort_bounded_ms", "sort_bounded_passes",

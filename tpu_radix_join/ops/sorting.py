@@ -10,64 +10,52 @@ order-independent within an equal-key run), and on v5e an unstable
 Centralised here so the *implementation* is one edit for every caller:
 ``merge_count.presort_keys``, the build/probe bucket paths, chunked.py,
 the verify xor-fold, and the grouped codec all route through these three
-functions, and as of PR 12 each resolves between two arms at trace time:
+functions, and each resolves between two arms at trace time:
 
-  * ``xla`` — ``jax.lax.sort`` (the pre-kernel floor);
+  * ``xla`` — ``jax.lax.sort``;
   * ``pallas`` / ``pallas_interpret`` — the Pallas LSD radix sort
     (ops/pallas/radix_sort.py): 4 digit passes worst case for uint32,
     fewer when a key bound shrinks the effective width, no compare
     network at all.
 
-Resolution mirrors ops/radix.resolve_partition_impl: ``auto`` (the
-default, process-bindable via ``set_default_sort_impl`` from
-JoinConfig.sort_impl) prefers the radix sort on a TPU backend above
-``PALLAS_SORT_MIN_ELEMS`` for the shapes it can express (equal-length 1-D
-uint32 lanes), and degrades to ``lax.sort`` LOUDLY when Pallas is
-unavailable — the SORTFALLBACK counter ticks ONCE per process and a
-log-once stderr line names the first site.  Structural ineligibility
-(batched 2-D sorts, non-uint32 lanes) routes to XLA quietly even when the
-kernel is forced: forcing selects the impl for the sorts the kernel can
-express, it does not redefine what it can express.
+``auto`` (the default, process-bindable via ``set_default_sort_impl``
+from JoinConfig.sort_impl) is ``xla`` for every size, shape and backend:
+on a TPU v5e ``lax.sort`` beats the radix sort at every size measured,
+2^18 to 67M elements, bounded keys or not, by 9x to 60x, and the 20M x
+20M join's program runs in 75 ms against 2647 ms with the radix arm
+(PERF.md §5).  The radix sort runs only when forced.  Structural
+ineligibility (batched 2-D sorts, non-uint32 lanes) routes to XLA even
+when the kernel is forced: forcing selects the impl for the sorts the
+kernel can express, it does not redefine what it can express.
 """
 
 from __future__ import annotations
 
-import sys
 from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
 
 from tpu_radix_join.observability import stages
-from tpu_radix_join.ops.pallas.merge_scan import pallas_available
 from tpu_radix_join.ops.pallas.radix_sort import radix_sort_pallas
-from tpu_radix_join.performance.measurements import SORTFALLBACK, SORTPASS
-
-#: below this many elements the fixed costs of the radix machinery (4
-#: kernel launches + 4 scatters worst case) beat its pass-count win over
-#: the O(log^2 n)-stage lax.sort, so ``auto`` keeps small sorts on XLA
-#: even on a TPU backend.  The planner's plan_sort arm uses the same
-#: threshold so predictions match trace-time selection.
-PALLAS_SORT_MIN_ELEMS = 1 << 18
+from tpu_radix_join.performance.measurements import SORTPASS
 
 SORT_IMPLS = ("auto", "xla", "pallas", "pallas_interpret")
 
-# Sort-impl auto-selection happens at TRACE time (these functions run
+# Sort-impl selection happens at TRACE time (these functions run
 # inside jit/shard_map bodies where no host counter can tick per
 # execution), so the observability hook lives at module level, exactly
 # like ops/radix's partition observer: the engine registers its
 # Measurements once and every traced sort site records which arm it took.
 _sort_observer: dict = {"meas": None}
 _default_impl: dict = {"impl": "auto"}
-_fallback_logged = False
-_fallback_ticked = False
 
 
 def install_sort_observer(measurements) -> None:
     """Register a performance.Measurements (or None) to receive SORTPASS
-    ticks, radix-sort spans, and the once-per-process SORTFALLBACK tick
-    from trace-time impl selection.  Process-global: the most recent
-    engine wins, which is the engine whose programs are being traced."""
+    ticks and radix-sort spans from trace-time impl selection.
+    Process-global: the most recent engine wins, which is the engine
+    whose programs are being traced."""
     _sort_observer["meas"] = measurements
 
 
@@ -94,26 +82,6 @@ def _sort_span(impl: str, site: str, elems: int):
     return m.span("radix_sort", impl=impl, site=site, elems=int(elems))
 
 
-def _note_fallback(site: str, elems: int, why: str) -> None:
-    """Auto-select degraded to lax.sort: tick SORTFALLBACK once per
-    process and log once instead of staying silent (a TPU run quietly
-    paying the sort floor where the radix kernel was expected is a perf
-    bug).  One tick, not one per sort site: the degrade is a per-process
-    backend fact, and a counter that scales with traced sort count would
-    bury the regress gate's 0-vs-1 signal in retrace noise."""
-    global _fallback_logged, _fallback_ticked
-    m = _sort_observer["meas"]
-    if m is not None and not _fallback_ticked:
-        _fallback_ticked = True
-        m.incr(SORTFALLBACK)
-    if not _fallback_logged:
-        _fallback_logged = True
-        print(f"[sorting] sort auto-select fell back to lax.sort at "
-              f"{site} ({elems} elems: {why}); further sorts degrade "
-              f"silently — force --sort-impl xla to acknowledge, or run "
-              f"a TPU backend for the radix arm", file=sys.stderr)
-
-
 def _radix_eligible(operands, dimension: int) -> bool:
     """Shapes the radix kernel expresses: equal-length 1-D uint32 lanes
     sorted along their only axis.  Batched (2-D) sorts and non-uint32
@@ -125,32 +93,16 @@ def _radix_eligible(operands, dimension: int) -> bool:
                and o.dtype == jnp.uint32 for o in operands)
 
 
-def resolve_sort_impl(impl: str | None, elems: int, site: str,
-                      eligible: bool = True) -> str:
+def resolve_sort_impl(impl: str | None, eligible: bool = True) -> str:
     """Resolve a sort ``impl`` request to a concrete arm.
 
     ``None`` reads the process default (``set_default_sort_impl``).
-    ``auto`` prefers the Pallas radix sort when the backend compiles
-    Mosaic, the operands are radix-eligible, and the sort is big enough
-    to amortize the pass machinery; a missing backend degrades to
-    ``xla`` with SORTFALLBACK visibility (once per process).  ``xla``
-    forces ``lax.sort``; ``pallas``/``pallas_interpret`` force the kernel
-    for every eligible sort (interpret = traced JAX ops, the tier-1 CPU
-    parity path)."""
+    ``auto`` and ``xla`` are ``lax.sort``; ``pallas``/``pallas_interpret``
+    force the radix kernel for every eligible sort (interpret = traced
+    JAX ops, the tier-1 CPU parity path)."""
     if impl is None:
         impl = _default_impl["impl"]
-    if impl == "xla":
-        return "xla"
-    if impl == "auto":
-        if not eligible:
-            return "xla"
-        if not pallas_available():
-            _note_fallback(site, elems, "Pallas unavailable")
-            return "xla"
-        if elems < PALLAS_SORT_MIN_ELEMS:
-            return "xla"
-        return "pallas"
-    if not eligible:
+    if impl in ("auto", "xla") or not eligible:
         return "xla"
     return impl
 
@@ -161,7 +113,7 @@ def sort_unstable(x: jnp.ndarray, dimension: int = -1, *,
                   key_bound: int | None = None) -> jnp.ndarray:
     """Unstable sort of one array along ``dimension``."""
     eligible = _radix_eligible((x,), dimension)
-    r = resolve_sort_impl(impl, x.size, "sort_unstable", eligible)
+    r = resolve_sort_impl(impl, eligible)
     if r in ("pallas", "pallas_interpret"):
         with _sort_span(r, "sort_unstable", x.size):
             return radix_sort_pallas(
@@ -175,7 +127,7 @@ def sort_kv_unstable(key: jnp.ndarray, *values: jnp.ndarray,
                      impl: str | None = None, key_bound: int | None = None):
     """Unstable key-value sort; returns (sorted key, *values in key order)."""
     eligible = _radix_eligible((key, *values), -1)
-    r = resolve_sort_impl(impl, key.size, "sort_kv_unstable", eligible)
+    r = resolve_sort_impl(impl, eligible)
     if r in ("pallas", "pallas_interpret"):
         with _sort_span(r, "sort_kv_unstable", key.size):
             return radix_sort_pallas(
@@ -194,8 +146,7 @@ def sort_lex_unstable(*operands: jnp.ndarray, num_keys: int,
     digit passes run first and stability chains them under the hi
     lane's."""
     eligible = _radix_eligible(operands, dimension)
-    r = resolve_sort_impl(impl, operands[0].size, "sort_lex_unstable",
-                          eligible)
+    r = resolve_sort_impl(impl, eligible)
     if r in ("pallas", "pallas_interpret"):
         with _sort_span(r, "sort_lex_unstable", operands[0].size):
             return radix_sort_pallas(

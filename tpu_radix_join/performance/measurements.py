@@ -151,11 +151,10 @@ PARTFALLBACK = "PARTFALLBACK"  # partition/histogram auto-select fell back to
 SORTPASS = "SORTPASS"      # Pallas LSD radix sorts selected at trace time
                            # (ops/sorting.py resolve_sort_impl); one per
                            # traced sort site, like PARTPASS
-SORTFALLBACK = "SORTFALLBACK"  # sort auto-select degraded to lax.sort
-                           # (Pallas unavailable on this backend) — ticked
-                           # ONCE per process (the decision is per-process,
-                           # not per-sort) and paired with a log-once
-                           # stderr line; 1 on a TPU backend is a regression
+SORTFALLBACK = "SORTFALLBACK"  # nothing ticks it: the sort's ``auto`` is
+                           # lax.sort, with no arm to fall back from; it
+                           # reads 0 and stays because the benchmark's
+                           # ``fallbacks`` check and chip_smoke.py read it
 FAILOVER = "FAILOVER"      # fleet queries failed over to another worker after
                            # the routed worker died mid-query (service/fleet.py)
 REPLAYN = "REPLAYN"        # journal intents replayed (failover retries plus

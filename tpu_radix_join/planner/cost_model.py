@@ -218,7 +218,7 @@ class SortPlan:
     vs the XLA sort emitter, with both arms' prices kept for the explain
     table (mirrors :class:`PartitionPlan` for destination grouping)."""
 
-    impl: str               # "pallas" | "xla"
+    impl: str               # "xla": what ``auto`` runs
     sort_ms: float          # the chosen arm
     pallas_ms: float        # bounded LSD digit passes + per-lane scatters
     xla_ms: float           # the stage-model lax.sort arm
@@ -228,52 +228,26 @@ class SortPlan:
 
 def plan_sort(profile: DeviceProfile, elems: int, lanes: int = 2,
               key_bound: Optional[int] = None, key_bits: int = 32,
-              lane_factor: float = 1.0, rows: int = 1,
-              pallas_ok: Optional[bool] = None) -> SortPlan:
-    """Price both sort arms and pick the cheaper available.
-
-    The radix arm's pass count comes from the workload's static key bound
-    through the same :func:`~tpu_radix_join.ops.pallas.radix_sort.
+              lane_factor: float = 1.0, rows: int = 1) -> SortPlan:
+    """Price both sort arms and bind ``xla``, the arm ops/sorting's
+    ``auto`` picks at every size: on the chip ``lax.sort`` beats the
+    radix sort everywhere measured, so the plan never binds an arm that
+    ``auto`` would not pick.  The radix arm stays priced for the explain
+    table, its pass count from the workload's static key bound through
+    the same :func:`~tpu_radix_join.ops.pallas.radix_sort.
     num_radix_passes` rule the kernel itself skips passes by, so a
-    16-bit-bounded key is priced at 2 passes, not 4.  Availability and
-    the small-sort floor mirror ops/sorting's auto-select
-    (``PALLAS_SORT_MIN_ELEMS``) so the plan never binds an arm the
-    runtime would refuse; batched (``rows > 1``) sorts are structurally
-    xla — the 1-D kernel cannot express them.  ``pallas_ok=None`` probes
-    the backend; tests pass an explicit bool to price either arm
-    portably."""
+    16-bit-bounded key is priced at 2 passes, not 4; batched
+    (``rows > 1``) sorts are ones the 1-D kernel cannot express."""
     from tpu_radix_join.ops.pallas.radix_sort import num_radix_passes
-    from tpu_radix_join.ops.sorting import PALLAS_SORT_MIN_ELEMS
     xla = sort_ms(profile, elems, lane_factor, rows)
     passes = num_radix_passes(key_bound, key_bits)
     pal = radix_sort_ms(profile, elems, passes, lanes)
-    if rows > 1:
-        return SortPlan(
-            impl="xla", sort_ms=xla, pallas_ms=pal, xla_ms=xla,
-            passes=passes,
-            note=f"batched {rows}-row sort: the radix kernel is 1-D only")
-    if pallas_ok is None:
-        from tpu_radix_join.ops.pallas.merge_scan import pallas_available
-        pallas_ok = pallas_available()
-    if not pallas_ok:
-        return SortPlan(
-            impl="xla", sort_ms=xla, pallas_ms=pal, xla_ms=xla,
-            passes=passes, note="pallas unavailable: lax.sort")
-    if elems < PALLAS_SORT_MIN_ELEMS:
-        return SortPlan(
-            impl="xla", sort_ms=xla, pallas_ms=pal, xla_ms=xla,
-            passes=passes,
-            note=(f"{elems} elems under the {PALLAS_SORT_MIN_ELEMS} "
-                  f"pallas sort floor"))
-    if pal <= xla:
-        return SortPlan(
-            impl="pallas", sort_ms=pal, pallas_ms=pal, xla_ms=xla,
-            passes=passes,
-            note=(f"{passes}-pass radix {pal:.2f} ms vs "
-                  f"{xla:.2f} ms lax.sort"))
-    return SortPlan(
-        impl="xla", sort_ms=xla, pallas_ms=pal, xla_ms=xla, passes=passes,
-        note=f"lax.sort {xla:.2f} ms beats {passes}-pass radix {pal:.2f} ms")
+    note = (f"batched {rows}-row sort: the radix kernel is 1-D only"
+            if rows > 1 else
+            f"lax.sort {xla:.2f} ms; forced {passes}-pass radix "
+            f"{pal:.2f} ms")
+    return SortPlan(impl="xla", sort_ms=xla, pallas_ms=pal, xla_ms=xla,
+                    passes=passes, note=note)
 
 
 def network_fanout_bits(w: Workload) -> int:
