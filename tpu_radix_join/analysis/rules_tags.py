@@ -7,7 +7,8 @@ which is silent — nobody decided it.  This rule cross-checks the two
 vocabularies in *both* directions:
 
   * **emitted but undeclared** — every first argument of a
-    ``Measurements`` ``incr``/``start``/``stop``/``add_time_us`` call
+    ``Measurements`` ``incr``/``start``/``stop``/``add_time_us``/``timed``/
+    ``begin`` call
     (string literal, or an UPPER_CASE name resolved against the
     measurements-module constant table) must be declared in regress.py:
     exact membership in ``_HIGHER_BETTER`` / ``_COST_TAGS`` /
@@ -33,7 +34,7 @@ from typing import Dict, List, Optional, Tuple
 
 from tpu_radix_join.analysis.core import Finding, Repo, rule
 
-EMIT_METHODS = {"incr", "start", "stop", "add_time_us"}
+EMIT_METHODS = {"incr", "start", "stop", "add_time_us", "timed", "begin"}
 
 #: file holding the pin registries (never scanned for liveness hits)
 REGRESS_REL = "tpu_radix_join/observability/regress.py"

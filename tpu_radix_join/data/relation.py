@@ -276,6 +276,35 @@ def device_range(start, n: int, global_size: int, seed: int,
                              modulo, wide)
 
 
+@functools.lru_cache(maxsize=32)
+def _sharded_program(mesh, axes, kind: str, local: int, global_size: int,
+                     seed: int, wide: bool, modulo: Optional[int],
+                     zipf_theta: Optional[float], key_domain: Optional[int]):
+    """The jitted on-device generator of one relation spec over ``mesh``
+    (:meth:`Relation.generate_sharded`), kept per spec: a spec generated
+    again, as a session's placed-relation LRU does after an eviction, is
+    not traced again."""
+    from jax.sharding import PartitionSpec
+
+    if kind == "zipf":
+        head_cdf, tail_keys = zipf_tables(zipf_theta, key_domain)
+        c_dev = jnp.asarray(head_cdf)
+        tk_dev = jnp.asarray(tail_keys)
+
+    def gen():
+        i = jax.lax.axis_index(axes)   # flat rank over the (maybe
+        lo = i.astype(jnp.uint32) * jnp.uint32(local)   # hierarchical) mesh
+        if kind == "zipf":
+            return _zipf_range(lo, local, c_dev, tk_dev, key_domain, seed,
+                               wide)
+        return _device_range(lo, local, global_size, seed, modulo, wide)
+
+    spec = PartitionSpec(axes)
+    out_specs = (spec, spec, spec) if wide else (spec, spec)
+    return jax.jit(jax.shard_map(
+        gen, mesh=mesh, in_specs=(), out_specs=out_specs))
+
+
 class Relation:
     """A logical relation: a global keyspace spec + per-shard generators.
 
@@ -485,31 +514,13 @@ class Relation:
         if n != self.num_nodes:
             raise ValueError(
                 f"mesh has {n} devices, relation expects {self.num_nodes}")
-        local = self.local_size
         wide = self.key_bits == 64
-        gs = self.global_size
-        seed = self.seed
-        kind = self.kind
-        modulo = self.modulo if self.kind == "modulo" else None
-        if kind == "zipf":
-            head_cdf, tail_keys = self._zipf_tables_cached()
-            c_dev = jnp.asarray(head_cdf)
-            tk_dev = jnp.asarray(tail_keys)
-            domain = self.key_domain
-        from jax.sharding import PartitionSpec
-
-        def gen():
-            i = jax.lax.axis_index(axes)   # flat rank over the (maybe
-            lo = i.astype(jnp.uint32) * jnp.uint32(local)   # hierarchical) mesh
-            if kind == "zipf":
-                return _zipf_range(lo, local, c_dev, tk_dev, domain, seed,
-                                   wide)
-            return _device_range(lo, local, gs, seed, modulo, wide)
-
-        spec = PartitionSpec(axes)
-        out_specs = (spec, spec, spec) if wide else (spec, spec)
-        out = jax.jit(jax.shard_map(
-            gen, mesh=mesh, in_specs=(), out_specs=out_specs))()
+        zipf = self.kind == "zipf"
+        out = _sharded_program(
+            mesh, axes, self.kind, self.local_size, self.global_size,
+            self.seed, wide, self.modulo if self.kind == "modulo" else None,
+            self.zipf_theta if zipf else None,
+            self.key_domain if zipf else None)()
         if wide:
             key, hi, rid = out
             return TupleBatch(key=key, rid=rid, key_hi=hi)

@@ -176,6 +176,9 @@ _COST_TAGS = {"JTOTAL", "JPROC", "JHIST", "JMPI", "JCOMPILE", "SWINALLOC",
               "BPBUILD", "BPPROBE", "VCHK",
               "RETRYN", "BACKOFFMS", "RETRIES",
               "QREJECT", "QDEADLINE", "QDEGRADED", "BRKTRIP",
+              # the session's own host phases and its stale-version
+              # refusals (service/session.py)
+              "QWAIT", "QSERVE", "QTABLE", "QUPDATE", "QFINISH", "QSTALE",
               "VFAIL", "VREPAIR",
               "PARTPASS", "SORTPASS",
               "MWINBYTES", "PACKRATIO",
@@ -202,6 +205,9 @@ NEUTRAL_TAGS = {"RTUPLES", "STUPLES", "RESULTS",
                 "MWINPUTCNT", "WINCAPR", "WINCAPS", "XSTAGES",
                 "BPBUILDTUPLES", "BPPROBETUPLES",
                 "VCHKN", "QADMIT", "BRKPROBE",
+                # registered-table lookups and updates, and full
+                # executions, scale with the traffic
+                "QTABLEHIT", "QUPDATEN", "QEXEC",
                 "FINJECT", "CKPTSAVE", "CKPTLOAD", "GRIDPAIRS",
                 "STATICMEM",
                 # admissions describe the scenario (a grow arm admits by

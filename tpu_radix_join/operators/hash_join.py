@@ -1936,7 +1936,12 @@ class HashJoin:
         use_split = (self.config.measure_phases
                      and not self._single_node_sort_probe())
         vchk = None
-        for attempt in range(self.config.max_retries + 1):
+        # a warm start's capacities were measured on other data of these
+        # shapes: when they fall short, the first retry measures this
+        # join's, as a cold start would, and spends none of max_retries
+        resize = int(warm is not None)
+        last = self.config.max_retries + resize
+        for attempt in range(last + 1):
             self._check_cancel("probe")
             if use_split:
                 # config.__post_init__ rejects verify + measure_phases, so
@@ -1960,6 +1965,15 @@ class HashJoin:
             diag = self._flags_to_diag(flags)
             if not flags.any() or not self._retryable(diag):
                 break
+            if m and attempt < last:
+                # when retries are exhausted the last attempt IS the result
+                # — keep its time (see _rollback_attempt)
+                self._rollback_attempt(m, dts)
+            if warm is not None:
+                warm = None
+                cap_r, cap_s, skew_plan = self._measure_capacities(r, s)
+                local_slack = 1
+                continue
             # capacity shortfall: double only the shapes that fell short and
             # respecialize (detect-and-retry, SURVEY.md section 7.4 item 1)
             if diag["shuffle_overflow_r_tuples"]:
@@ -1970,11 +1984,7 @@ class HashJoin:
                 local_slack *= 2
             if diag["hot_overflow"]:
                 skew_plan = (skew_plan[0], 2 * skew_plan[1])
-            if m and attempt < self.config.max_retries:
-                # when retries are exhausted the last attempt IS the result
-                # — keep its time (see _rollback_attempt)
-                self._rollback_attempt(m, dts)
-            self._retry_backoff(attempt)
+            self._retry_backoff(attempt - resize)
         if (flags.any() and self._retryable(diag)
                 and self.config.fallback == "chunked"):
             # retries exhausted on a retryable (capacity) failure: degrade

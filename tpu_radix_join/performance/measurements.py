@@ -101,6 +101,19 @@ QADMIT = "QADMIT"          # queries admitted by the service queue
 QREJECT = "QREJECT"        # queries rejected at admission (depth / quota)
 QDEADLINE = "QDEADLINE"    # queries cancelled by their deadline
 QWARM = "QWARM"            # warm queries (capacity-cache hit: no sizing pass)
+QWAIT = "QWAIT"            # admission wait, submit to dequeue, summed over
+                           # queries (one open interval per queued query)
+QSERVE = "QSERVE"          # session time of one query, dequeue to outcome
+QTABLE = "QTABLE"          # table resolution: (name, version) -> placed lanes
+QUPDATE = "QUPDATE"        # in-place update of a registered table's key lane
+QFINISH = "QFINISH"        # the session's finish: outcome and accounting
+QTABLEHIT = "QTABLEHIT"    # registered tables resolved for queries
+QUPDATEN = "QUPDATEN"      # registered-table updates (each bumps a version)
+QSTALE = "QSTALE"          # queries refused because a table they name is
+                           # older than the version they were admitted under
+QEXEC = "QEXEC"            # queries served by a full engine execution
+                           # (served_by="execute"; the fast paths count
+                           # under RCHIT, BATCHQ and DELTAMERGE)
 QDEGRADED = "QDEGRADED"    # queries served by the degraded fallback engine
 BRKTRIP = "BRKTRIP"        # circuit-breaker trips (closed/half-open -> open)
 BRKPROBE = "BRKPROBE"      # half-open health probes dispatched
@@ -318,6 +331,28 @@ class Measurements:
             # JTOTAL still happened on the timeline, under its own span)
             self._tracer.end(key)
         return dt
+
+    def timed(self, key: str):
+        """``with m.timed(key):`` -- :meth:`start` and :meth:`stop`."""
+        import contextlib
+
+        @contextlib.contextmanager
+        def _ctx():
+            self.start(key)
+            try:
+                yield
+            finally:
+                self.stop(key)
+
+        return _ctx()
+
+    def begin(self, key: str) -> "Interval":
+        """An interval of ``key`` that may overlap others of the same key
+        (one per queued query, where :meth:`start` keeps one per key):
+        its ``end()`` adds it to ``times_us[key]`` and closes its
+        ``trj.<key>`` profiler span."""
+        self.flightrec.record("begin", key)
+        return Interval(self, key)
 
     def add_time_us(self, key: str, us: float) -> None:
         self.times_us[key] += us
@@ -595,6 +630,24 @@ class Measurements:
                         m.counters[key] = int(value)
             out.append(m)
         return out
+
+
+class Interval:
+    """One open interval of :meth:`Measurements.begin`."""
+
+    def __init__(self, measurements: Measurements, key: str):
+        self._m, self.key = measurements, key
+        self._annotation = jax.profiler.TraceAnnotation(SPAN_PREFIX + key)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+
+    def end(self) -> float:
+        """Close the interval once; microseconds it lasted."""
+        us = (time.perf_counter() - self._t0) * 1e6
+        self._annotation.__exit__(None, None, None)
+        self._m.add_time_us(self.key, us)
+        self._m.flightrec.record("end", self.key, us=round(us, 1))
+        return us
 
 
 DEVICE_PLANE = "/device:"

@@ -23,8 +23,9 @@ event, and the stale entry is overwritten on the next store).
 
 The key is (profile, shapes, config) — not data content — so a warm
 capacity is an *educated guess* for a rerun over different data of the
-same shape: the engine's capacity-overflow detect-and-retry loop remains
-the correctness backstop, exactly as for a cold mis-sizing.
+same shape: when it falls short, the engine measures the join's own
+capacities and runs it again (its first retry, whatever ``max_retries``
+says), and its detect-and-retry loop remains the backstop after that.
 
 The **manifest** covers multi-host resume: rank 0 records the rank count
 and profile fingerprint next to the cached plans; a later run resuming

@@ -28,7 +28,7 @@ import collections
 import threading
 from typing import Deque, Dict, Optional
 
-from tpu_radix_join.performance.measurements import QADMIT, QREJECT
+from tpu_radix_join.performance.measurements import QADMIT, QREJECT, QWAIT
 from tpu_radix_join.robustness.retry import ADMISSION_REJECTED
 
 QUEUE_FULL = "queue_full"
@@ -68,6 +68,8 @@ class AdmissionQueue:
         self._lock = threading.Lock()
         self._pending: Deque[object] = collections.deque()
         self._in_flight: Dict[str, int] = collections.defaultdict(int)
+        #: id(request) -> its open QWAIT interval, submit to dequeue
+        self._waits: Dict[int, object] = {}
         self.admitted = 0
         self.rejected = 0
 
@@ -104,6 +106,7 @@ class AdmissionQueue:
                 self.admitted += 1
                 if m is not None:
                     m.incr(QADMIT)
+                    self._waits[id(request)] = m.begin(QWAIT)
                 return
             self.rejected += 1
         if m is not None:
@@ -116,7 +119,9 @@ class AdmissionQueue:
         """Oldest pending request, or None when the queue is empty.  The
         tenant's slot stays held until :meth:`done`."""
         with self._lock:
-            return self._pending.popleft() if self._pending else None
+            request = self._pending.popleft() if self._pending else None
+        self._dequeued([request] if request is not None else [])
+        return request
 
     def pop_matching(self, pred, limit: int) -> list:
         """Up to ``limit`` pending requests satisfying ``pred``, removed
@@ -136,7 +141,15 @@ class AdmissionQueue:
                 else:
                     keep.append(request)
             self._pending = keep
+        self._dequeued(taken)
         return taken
+
+    def _dequeued(self, requests) -> None:
+        """End the admission wait of each request leaving the queue."""
+        for request in requests:
+            wait = self._waits.pop(id(request), None)
+            if wait is not None:
+                wait.end()
 
     def done(self, request) -> None:
         """Release the tenant slot taken at submit (call exactly once per

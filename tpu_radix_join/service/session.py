@@ -14,7 +14,13 @@ queries:
     sizing pre-pass (no JHIST dispatch), via the cache's new in-process
     hot layer;
   * **placed relations** — a small LRU of device-resident inputs, so the
-    closed-loop bench's repeated workloads skip generation + transfer.
+    closed-loop bench's repeated workloads skip generation + transfer;
+  * **registered tables** — a user's relations, placed once under a name
+    (:meth:`JoinSession.register_table`), joined by name, and updated in
+    place under a new version (:meth:`JoinSession.update_table`).  The
+    placed-relation LRU and the result cache key a table by (name,
+    version); the plan cache keys on shapes, as for seeded specs.  A
+    query admitted after an update reads that update or a later one.
 
 In front of the engine sit the robustness pieces this module composes
 (each one classified, none of them able to take the session down):
@@ -41,21 +47,28 @@ In front of the engine sit the robustness pieces this module composes
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import functools
 import sys
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 from tpu_radix_join.core.config import JoinConfig, ServiceConfig
 from tpu_radix_join.performance.measurements import (BATCHN, BATCHQ,
                                                      COMPILEMS, DELTAMERGE,
                                                      JHIST, MEPOCH, NCOMPILE,
                                                      QDEADLINE, QDEGRADED,
-                                                     QWARM, RANKLOST,
-                                                     RECOVERMS, RECOVERN)
+                                                     QEXEC, QFINISH, QSERVE,
+                                                     QSTALE, QTABLE,
+                                                     QTABLEHIT, QUPDATE,
+                                                     QUPDATEN, QWARM, RANKLOST,
+                                                     RCHIT, RECOVERMS,
+                                                     RECOVERN)
 from tpu_radix_join.robustness import faults as _faults
 from tpu_radix_join.robustness.retry import (BACKEND_UNAVAILABLE,
-                                             DEADLINE_EXCEEDED, OK)
+                                             DEADLINE_EXCEEDED, OK,
+                                             REQUEST_ERROR)
 from tpu_radix_join.service.admission import AdmissionQueue, AdmissionRejected
 from tpu_radix_join.service.breaker import HALF_OPEN, CircuitBreaker
 from tpu_radix_join.service.deadline import Deadline, DeadlineExceeded
@@ -65,6 +78,8 @@ from tpu_radix_join.service.slo import SLORecorder
 #: failure_class still yields a terminal outcome (the session survives),
 #: but chaos/soak treats this string as an isolation violation
 UNCLASSIFIED = "unclassified"
+#: a query named a table older than the version it was admitted under
+STALE_VERSION = "stale_version"
 
 
 class BackendUnavailable(ConnectionError):
@@ -72,6 +87,28 @@ class BackendUnavailable(ConnectionError):
     breaker is open and CPU degrade is off."""
 
     failure_class = BACKEND_UNAVAILABLE
+
+
+class UnknownTable(KeyError):
+    """A query or update named a table the session does not hold."""
+
+    failure_class = REQUEST_ERROR
+
+
+class StaleTable(RuntimeError):
+    """A table is older than the version its query was admitted under:
+    the session refuses rather than answer from it."""
+
+    failure_class = STALE_VERSION
+
+
+@dataclasses.dataclass
+class _Table:
+    """One registered table: its lanes on the engine's mesh, and the
+    version they hold."""
+
+    batch: object                   # data.tuples.TupleBatch
+    version: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,6 +129,16 @@ class QueryRequest:
     #: the O(N+Δ) delta-merge fast path when residency is enabled
     #: (ServiceConfig.resident_budget_bytes > 0), full path otherwise
     delta_tuples_per_node: int = 0
+    #: registered tables to join (:meth:`JoinSession.register_table`),
+    #: both named or neither; named, they replace the seeded spec above
+    inner: Optional[str] = None
+    outer: Optional[str] = None
+
+    def __post_init__(self):
+        if (self.inner is None) != (self.outer is None):
+            raise ValueError("a table query names both inner and outer")
+        if self.inner is not None and self.delta_tuples_per_node:
+            raise ValueError("a table query has no delta_tuples_per_node")
 
     @classmethod
     def from_json(cls, obj: dict) -> "QueryRequest":
@@ -125,13 +172,16 @@ class QueryOutcome:
     #: cache_hit (result cache, no execution), batched (fused multi-query
     #: program), delta_merge (O(N+Δ) incremental path)
     served_by: str = "execute"
+    #: {table: version} a table query was computed on
+    table_versions: Optional[Dict[str, int]] = None
 
     def to_json(self) -> dict:
         out = dataclasses.asdict(self)
         out["latency_ms"] = round(self.latency_ms, 3)
-        if out.get("bundle") is None:
-            # successful queries keep the pre-forensics line shape
-            out.pop("bundle", None)
+        for key in ("bundle", "table_versions"):
+            if out.get(key) is None:
+                # spec queries that succeed keep the line shape they had
+                out.pop(key, None)
         return out
 
 
@@ -216,6 +266,14 @@ class JoinSession:
         self._cpu_engine = None         # built lazily on first open-state query
         self._place_cache: "collections.OrderedDict" = \
             collections.OrderedDict()
+        #: registered tables by name, and the last version handed out:
+        #: versions never repeat within a session, so re-registering a
+        #: name cannot alias an older (name, version)
+        self._tables: Dict[str, _Table] = {}
+        self._version = 0
+        #: id(queued table query) -> {table: version} current when it was
+        #: admitted: it is never answered from an older version
+        self._admitted: Dict[int, Dict[str, int]] = {}
         # ------------------------------------------------ serving fast paths
         from tpu_radix_join.service.resident import ResidentStateManager
         from tpu_radix_join.service.resultcache import ResultCache
@@ -264,9 +322,15 @@ class JoinSession:
         :meth:`rejection_outcome`)."""
         if self._closed:
             raise RuntimeError("session is closed")
+        if request.inner is not None:
+            self._admitted[id(request)] = {
+                name: self._tables[name].version
+                for name in (request.inner, request.outer)
+                if name in self._tables}
         try:
             self.queue.submit(request)
         except AdmissionRejected:
+            self._admitted.pop(id(request), None)
             self.slo.record_rejection()
             raise
 
@@ -290,9 +354,10 @@ class JoinSession:
         if request is None:
             return None
         try:
-            return self._serve_one(request)
+            with self._timed(QSERVE):
+                return self._serve_one(request)
         finally:
-            self.queue.done(request)
+            self._release(request)
 
     def _serve_one(self, request: QueryRequest) -> QueryOutcome:
         hit = self.try_cache(request)
@@ -338,28 +403,43 @@ class JoinSession:
         group = [first]
         try:
             if (self.service.batch_window_ms > 0
-                    and first.delta_tuples_per_node == 0):
+                    and first.delta_tuples_per_node == 0
+                    and first.inner is None):
                 sig = batch_signature(first)
                 group += self.queue.pop_matching(
                     lambda r: (batch_signature(r) == sig
-                               and r.delta_tuples_per_node == 0),
+                               and r.delta_tuples_per_node == 0
+                               and r.inner is None),
                     self.service.batch_max_queries - 1)
             if len(group) == 1:
                 return [self._serve_one(first)]
             return self._execute_batched(group)
         finally:
             for request in group:
-                self.queue.done(request)
+                self._release(request)
+
+    def _release(self, request: QueryRequest) -> None:
+        """A popped query is done: free its tenant slot and its record."""
+        self._admitted.pop(id(request), None)
+        self.queue.done(request)
 
     # ----------------------------------------------------- result cache tier
     def _epoch(self) -> Optional[int]:
         return self.membership.epoch if self.membership is not None else None
 
-    def _content_fp(self, request: QueryRequest) -> str:
+    def _content_fp(self, request: QueryRequest,
+                    versions: Optional[Dict[str, int]] = None) -> str:
+        """The result cache's key; a table query's carries the versions
+        of its tables (those current, unless ``versions`` names the ones
+        an answer was computed on)."""
         from tpu_radix_join.service.resultcache import content_fingerprint
-        return content_fingerprint(
-            request, config_fp=dataclasses.asdict(self.config),
-            epoch=self._epoch())
+        config_fp = dataclasses.asdict(self.config)
+        if request.inner is not None:
+            config_fp["tables"] = versions or {
+                name: self._tables[name].version if name in self._tables
+                else None for name in (request.inner, request.outer)}
+        return content_fingerprint(request, config_fp=config_fp,
+                                   epoch=self._epoch())
 
     def try_cache(self, request: QueryRequest) -> Optional[QueryOutcome]:
         """Serve ``request`` from the result cache without executing, or
@@ -383,7 +463,8 @@ class JoinSession:
             matches=payload.get("matches"), expected=payload.get("expected"),
             engine=payload.get("engine", "primary"),
             warm=True, breaker_state=self.breaker.state,
-            detail="result cache hit", served_by="cache_hit")
+            detail="result cache hit", served_by="cache_hit",
+            table_versions=payload.get("table_versions"))
         self.slo.record(request.tenant, out.latency_ms, ok=True)
         self.outcomes.append(out)
         return out
@@ -398,9 +479,9 @@ class JoinSession:
                 or out.matches is None):
             return
         self.result_cache.put(
-            self._content_fp(request),
+            self._content_fp(request, out.table_versions),
             {"matches": out.matches, "expected": out.expected,
-             "engine": out.engine},
+             "engine": out.engine, "table_versions": out.table_versions},
             epoch=self._epoch())
 
     # ------------------------------------------------------ micro-batch tier
@@ -643,6 +724,8 @@ class JoinSession:
                 m.event("query_failed", query_id=request.query_id,
                         failure_class=cls, error=repr(e)[:200])
         latency_ms = (time.perf_counter() - t0) * 1e3
+        if m is not None and served_by == "execute":
+            m.incr(QEXEC)
         out = QueryOutcome(
             query_id=request.query_id, tenant=request.tenant,
             status=status, failure_class=cls, latency_ms=latency_ms,
@@ -653,6 +736,119 @@ class JoinSession:
                         failure_class=None if cls == OK else cls)
         self.outcomes.append(out)
         return out
+
+    # ---------------------------------------------------- registered tables
+    def _next_version(self) -> int:
+        self._version += 1
+        return self._version
+
+    def _timed(self, tag: str):
+        m = self.measurements
+        return m.timed(tag) if m is not None else contextlib.nullcontext()
+
+    def register_table(self, name: str, batch) -> int:
+        """Hold ``batch`` (a ``TupleBatch``: key and rid lanes, on the host
+        or the device) on the engine's mesh under ``name``, and return its
+        version.  The session owns the lanes from here on: no query
+        donates or consumes them, and :meth:`update_table` rewrites the
+        key lane in place.  Registering a name again replaces its table
+        under a new version."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from tpu_radix_join.data.tuples import TupleBatch
+        if self._closed:
+            raise RuntimeError("session is closed")
+        if batch.size % self.config.num_nodes:
+            raise ValueError(f"table {name!r} has {batch.size} rows, not a "
+                             f"multiple of {self.config.num_nodes} nodes")
+        sharding = NamedSharding(self.engine.mesh, P(self.config.mesh_axes))
+        placed = TupleBatch(*(None if lane is None
+                              else jax.device_put(lane, sharding)
+                              for lane in batch))
+        self._forget_placed(name)
+        self._tables[name] = _Table(placed, self._next_version())
+        return self._tables[name].version
+
+    def table_version(self, name: str) -> int:
+        return self._table(name).version
+
+    def tables(self) -> Dict[str, dict]:
+        """{name: {"version", "rows", "bytes"}} of every registered table:
+        the heartbeat's and the summary's view."""
+        return {name: {"version": t.version, "rows": int(t.batch.size),
+                       "bytes": sum(int(lane.nbytes) for lane in t.batch
+                                    if lane is not None)}
+                for name, t in sorted(self._tables.items())}
+
+    def update_table(self, name: str, positions, keys) -> Tuple[int, object]:
+        """Rewrite table ``name``'s key lane in place: ``keys`` go to
+        ``positions`` (distinct row numbers; one at or past the end is
+        skipped), and the table takes a new version.  Returns ``(version,
+        previous)``: the new version and, as a device array, the keys the
+        positions held before (0 where skipped), which a later update can
+        put back.  The update is dispatched, not waited for: queries that
+        follow read it in device order."""
+        import numpy as np
+
+        table = self._table(name)
+        positions = np.asarray(positions, np.int32)
+        inside = positions[positions < table.batch.size]
+        if (positions < 0).any() or np.unique(inside).size != inside.size:
+            raise ValueError("update positions must be distinct rows")
+        m = self.measurements
+        with self._timed(QUPDATE):
+            key, previous = _key_rewriter()(table.batch.key, positions,
+                                            keys)
+            self._forget_placed(name)
+            table.batch = table.batch._replace(key=key)
+            table.version = self._next_version()
+        if m is not None:
+            m.incr(QUPDATEN)
+        return table.version, previous
+
+    def _table(self, name: str) -> _Table:
+        table = self._tables.get(name)
+        if table is None:
+            raise UnknownTable(f"no table named {name!r} is registered")
+        return table
+
+    def _forget_placed(self, name: str) -> None:
+        """Drop every placed-relation entry of table ``name``: each names
+        a version that is about to be superseded."""
+        for key in [k for k in self._place_cache
+                    if k[1:3] == ("table", name)]:
+            del self._place_cache[key]
+
+    def _resolve_tables(self, engine, request: QueryRequest):
+        """(inner batch, outer batch, {name: version}) of a table query,
+        placed for ``engine`` through the placed-relation LRU under
+        (engine, "table", name, version)."""
+        m = self.measurements
+        floor = self._admitted.get(id(request), {})
+        batches, versions = [], {}
+        with self._timed(QTABLE):
+            for name in (request.inner, request.outer):
+                table = self._table(name)
+                if table.version < floor.get(name, 0):
+                    if m is not None:
+                        m.incr(QSTALE)
+                    raise StaleTable(
+                        f"table {name!r} is at version {table.version}, "
+                        f"the query was admitted under {floor[name]}")
+                key = (id(engine), "table", name, table.version)
+                batch = self._place_cache.pop(key, None)
+                if batch is None:
+                    batch = (table.batch if engine is self.engine
+                             else _on_mesh(engine, table.batch))
+                self._place_cache[key] = batch
+                while len(self._place_cache) > self.service.place_cache_max:
+                    self._place_cache.popitem(last=False)
+                batches.append(batch)
+                versions[name] = table.version
+                if m is not None:
+                    m.incr(QTABLEHIT)
+        return batches[0], batches[1], versions
 
     # ------------------------------------------------------------ internals
     def _wire_elastic(self, engine) -> None:
@@ -718,15 +914,17 @@ class JoinSession:
         return batch
 
     def placed_bytes(self) -> int:
-        """Device bytes held by the placed-relation LRU (key + rid + wide
-        lanes of every cached batch) — the heartbeat/statusz gauge that
-        makes the ``place_cache_max`` knob observable."""
-        total = 0
-        for batch in self._place_cache.values():
+        """Device bytes held by the placed-relation LRU and the registered
+        tables (key + rid + wide lanes, each lane once) — the
+        heartbeat/statusz gauge that makes ``place_cache_max`` and the
+        tables observable."""
+        lanes = {}
+        for batch in [*self._place_cache.values(),
+                      *(t.batch for t in self._tables.values())]:
             for lane in batch:
                 if lane is not None and hasattr(lane, "nbytes"):
-                    total += int(lane.nbytes)
-        return total
+                    lanes[id(lane)] = int(lane.nbytes)
+        return sum(lanes.values())
 
     def _execute(self, request: QueryRequest) -> QueryOutcome:
         m = self.measurements
@@ -758,7 +956,7 @@ class JoinSession:
             m.flightrec.set_context(query_id=request.query_id,
                                     tenant=request.tenant)
         status, cls, detail = "ok", OK, ""
-        matches = expected = None
+        matches = expected = versions = None
         try:
             with span:
                 if not primary and not degraded:
@@ -773,10 +971,14 @@ class JoinSession:
                         f"injected backend outage (query "
                         f"{request.query_id})")
                 deadline.check("admitted")
-                inner, outer, expected = self._relations(request)
-                deadline.check("generated")
-                r_batch = self._place(engine, inner, "r", request)
-                s_batch = self._place(engine, outer, "s", request)
+                if request.inner is not None:
+                    r_batch, s_batch, versions = self._resolve_tables(
+                        engine, request)
+                else:
+                    inner, outer, expected = self._relations(request)
+                    deadline.check("generated")
+                    r_batch = self._place(engine, inner, "r", request)
+                    s_batch = self._place(engine, outer, "s", request)
                 deadline.check("placed")
                 result = engine.join_arrays(r_batch, s_batch,
                                             repeats=request.repeats)
@@ -824,6 +1026,10 @@ class JoinSession:
                         failure_class=cls, error=repr(e)[:200])
         finally:
             engine.cancel = None
+        # the session's finish, QFINISH: outcome and accounting (every
+        # step below that can fail is isolated, so the timer closes)
+        if m is not None:
+            m.start(QFINISH)
         latency_ms = (time.perf_counter() - t0) * 1e3
         trips0 = self.breaker.trips
         # warm = the sizing pre-pass did not run this query (plan-cache /
@@ -837,6 +1043,7 @@ class JoinSession:
                 m.incr(QWARM)
             if degraded:
                 m.incr(QDEGRADED)
+            m.incr(QEXEC)
         if primary:
             if cls == OK:
                 self.breaker.record_success()
@@ -872,7 +1079,7 @@ class JoinSession:
             engine="cpu_fallback" if degraded else "primary",
             degraded=degraded, warm=warm,
             breaker_state=self.breaker.state, detail=detail,
-            bundle=bundle)
+            bundle=bundle, table_versions=versions)
         self.slo.record(request.tenant, latency_ms, ok=(status == "ok"),
                         failure_class=None if cls == OK else cls,
                         degraded=degraded)
@@ -908,6 +1115,8 @@ class JoinSession:
             except Exception as e:   # noqa: BLE001 — isolation boundary
                 if m is not None:
                     m.event("ledger_error", error=repr(e)[:200])
+        if m is not None:
+            m.stop(QFINISH)
         return out
 
     def _write_bundle(self, request: QueryRequest, reason: str,
@@ -951,13 +1160,16 @@ class JoinSession:
                           "fused_queries": self.batch_queries_fused},
                 "placed_bytes": self.placed_bytes(),
                 "place_cache_entries": len(self._place_cache),
-                "place_cache_max": self.service.place_cache_max}
+                "place_cache_max": self.service.place_cache_max,
+                "tables": self.tables()}
 
     def _heartbeat_extra(self) -> dict:
         out = {"slo": self.slo.snapshot(),
                "breaker": self.breaker.snapshot(),
                "queue_depth": self.queue.depth(),
                "placed_bytes": self.placed_bytes()}
+        if self._tables:
+            out["tables"] = self.tables()
         if self.result_cache.max_entries:
             out["result_cache"] = self.result_cache.stats()
         if self.resident.budget_bytes:
@@ -975,7 +1187,10 @@ class JoinSession:
                    breaker_trips=self.breaker.trips,
                    breaker_probes=self.breaker.probes,
                    queue_rejected=self.queue.rejected,
-                   placed_bytes=self.placed_bytes())
+                   placed_bytes=self.placed_bytes(),
+                   tenant_queries=self.slo.counts())
+        if self._tables:
+            out["tables"] = self.tables()
         if self.result_cache.max_entries:
             cache = self.result_cache.stats()
             out["cache_hits"] = cache["hits"]
@@ -994,6 +1209,14 @@ class JoinSession:
             out["ncompile"] = int(m.counters.get(NCOMPILE, 0))
             out["compile_ms"] = int(m.counters.get(COMPILEMS, 0))
             out["recompile_storms"] = self._recompile_storms
+            counter = m.counters.get
+            out["served_by"] = {"execute": int(counter(QEXEC, 0)),
+                                "cache_hit": int(counter(RCHIT, 0)),
+                                "batched": int(counter(BATCHQ, 0)),
+                                "delta_merge": int(counter(DELTAMERGE, 0))}
+            out["table_hits"] = int(counter(QTABLEHIT, 0))
+            out["table_updates"] = int(counter(QUPDATEN, 0))
+            out["stale_rejections"] = int(counter(QSTALE, 0))
             if m.counters.get(RANKLOST):
                 out["ranks_lost"] = int(m.counters.get(RANKLOST, 0))
                 out["membership_epoch"] = int(m.counters.get(MEPOCH, 0))
@@ -1012,6 +1235,8 @@ class JoinSession:
             self._sampler.stop()
             self._sampler = None
         self._place_cache.clear()
+        self._tables.clear()
+        self._admitted.clear()
         self.result_cache.invalidate()
         self.resident.invalidate()
         self._resident_host.clear()
@@ -1032,8 +1257,35 @@ class JoinSession:
 
 
 def _null_ctx():
-    import contextlib
     return contextlib.nullcontext()
+
+
+@functools.cache
+def _key_rewriter():
+    """A jitted ``rewrite(key, pos, new) -> (key', previous)`` that writes
+    ``new`` into ``key`` at ``pos`` in place (``key`` is donated) and
+    returns what was there; a position past the end is skipped."""
+    import jax
+    import jax.numpy as jnp
+
+    def rewrite(key, pos, new):
+        previous = key.at[pos].get(mode="fill", fill_value=0)
+        return (key.at[pos].set(jnp.asarray(new, key.dtype), mode="drop"),
+                previous)
+
+    return jax.jit(rewrite, donate_argnums=0)
+
+
+def _on_mesh(engine, batch):
+    """``batch``'s lanes laid out over ``engine``'s mesh (a copy when the
+    engine is not the one the table was registered on)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sharding = NamedSharding(engine.mesh, P(engine.config.mesh_axes))
+    return type(batch)(*(None if lane is None
+                         else jax.device_put(lane, sharding)
+                         for lane in batch))
 
 
 def _as_list(out: Optional[QueryOutcome]) -> List[QueryOutcome]:

@@ -89,6 +89,10 @@ class SLORecorder:
     def tenants(self) -> List[str]:
         return sorted(self._lat_ms)
 
+    def counts(self) -> Dict[str, int]:
+        """Executed queries per tenant."""
+        return {t: len(self._lat_ms[t]) for t in self.tenants()}
+
     def snapshot(self) -> dict:
         """Flat SLO tag dict: heartbeat tick, final report, and BENCH JSON
         all speak this vocabulary."""
