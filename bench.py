@@ -416,161 +416,6 @@ def _run_partition_bench(check_baseline=None, size=1 << 24):
     return 0
 
 
-def _run_sort_bench(check_baseline=None, size=1 << 18):
-    """``--sort-bench``: A/B of the flat-sort engine — ``lax.sort`` (the
-    XLA emitter) versus the Pallas LSD radix sort
-    (ops/pallas/radix_sort.py, interpreted on this host) — across
-    key-bound widths and 1/2/3-lane tuples.
-
-    Correctness first, twice over: (1) every (lanes, bound) cell of a
-    small sweep must be oracle-exact against NumPy on BOTH arms — keys
-    non-decreasing and the row multiset preserved (exit 3 otherwise);
-    (2) two full 8-way host-CPU joins, one per forced ``sort_impl``
-    ("xla", "pallas_interpret"), must verify oracle-exact — so the
-    timing legs can never bless a wrong kernel.  The BENCH headline
-    ``value`` is the wall speedup (xla over pallas, higher is better —
-    expected < 1 in interpret mode on host CPU, and below 1 on a TPU
-    v5e too, where lax.sort wins at every size measured), the per-arm
-    walls land as lower-is-better tags, and ``sort_pass_unit_ms`` is the
-    reduced ms/Mtuple/pass constant the profile fitter recovers
-    (planner/calibrate.py BENCH_RADIX_SORT_METRIC).  The bounded-key leg
-    must run FEWER passes and land a lower wall than the unbounded leg
-    (the pass-skip is the whole point of carrying key bounds), also exit
-    3 on violation."""
-    from tpu_radix_join.utils.platform import force_host_cpu_devices
-    force_host_cpu_devices(8, respect_existing=True)
-
-    import jax
-    import jax.numpy as jnp
-    from tpu_radix_join.core.config import JoinConfig
-    from tpu_radix_join.data.relation import Relation
-    from tpu_radix_join.operators.hash_join import HashJoin
-    from tpu_radix_join.ops.pallas.radix_sort import (num_radix_passes,
-                                                      radix_pass_slots_pallas)
-    from tpu_radix_join.ops.sorting import (set_default_sort_impl,
-                                            sort_kv_unstable, sort_unstable)
-    from tpu_radix_join.performance import Measurements
-
-    # -- oracle sweep: both arms vs NumPy at every (lanes, bound) cell --
-    rng = np.random.default_rng(13)
-    n_small = 1 << 12
-    for bound in (None, 1 << 16):
-        hi = bound if bound is not None else 1 << 32
-        keys = rng.integers(0, hi, n_small, dtype=np.uint32)
-        vals = [rng.integers(0, 1 << 32, n_small, dtype=np.uint32)
-                for _ in range(2)]
-        for lanes in (1, 2, 3):
-            ops = [jnp.asarray(keys)] + [jnp.asarray(v)
-                                         for v in vals[:lanes - 1]]
-            for impl in ("xla", "pallas_interpret"):
-                if lanes == 1:
-                    out = [sort_unstable(ops[0], impl=impl,
-                                         key_bound=bound)]
-                else:
-                    out = list(sort_kv_unstable(*ops, impl=impl,
-                                                key_bound=bound))
-                got = [np.asarray(o) for o in out]
-                ok = bool(np.all(got[0] == np.sort(keys)))
-                # row-multiset preservation: canonicalize both sides by
-                # lexicographic row order (equal keys may order their
-                # value lanes differently per arm — both are unstable)
-                raw = [keys] + vals[:lanes - 1]
-                perm_in = np.lexsort(tuple(reversed(raw)))
-                perm_out = np.lexsort(tuple(reversed(got)))
-                ok = ok and all(
-                    bool(np.all(r[perm_in] == g[perm_out]))
-                    for r, g in zip(raw, got))
-                if not ok:
-                    print(f"ERROR: sort oracle mismatch (impl={impl}, "
-                          f"lanes={lanes}, bound={bound})", file=sys.stderr)
-                    sys.exit(3)
-    print(f"note: sort oracle-exact on both arms "
-          f"({n_small} keys x bounds (None, 1<<16) x 1/2/3 lanes)",
-          file=sys.stderr)
-
-    # -- end-to-end: one full join per forced sort engine --
-    nodes, per_node = 8, 1 << 15
-    inner = Relation(per_node * nodes, nodes, "unique", seed=31)
-    outer = Relation(per_node * nodes, nodes, "unique", seed=32)
-    expected = inner.expected_matches(outer)
-    fallbacks = 0
-    for impl in ("xla", "pallas_interpret"):
-        meas = Measurements(node_id=0, num_nodes=nodes)
-        eng = HashJoin(JoinConfig(num_nodes=nodes, verify="check",
-                                  sort_impl=impl), measurements=meas)
-        res = eng.join(inner, outer)
-        if not res.ok:
-            print(f"ERROR: verification failed (sort_impl={impl}): "
-                  f"{res.failure}", file=sys.stderr)
-            sys.exit(3)
-        if expected is not None and res.matches != expected:
-            print(f"ERROR: matches {res.matches} != oracle {expected} "
-                  f"(sort_impl={impl})", file=sys.stderr)
-            sys.exit(3)
-        fallbacks = max(fallbacks, meas.counters.get("SORTFALLBACK", 0))
-        print(f"note: join oracle-exact (sort_impl={impl}, "
-              f"{per_node * nodes} tuples/side)", file=sys.stderr)
-    set_default_sort_impl("auto")        # don't leak the forced engine
-
-    # -- timing legs: flat 2-lane kv sort at bench scale --
-    n = size
-    keys = jnp.asarray(rng.integers(0, 1 << 32, n, dtype=np.uint32))
-    rids = jnp.arange(n, dtype=jnp.uint32)
-    bounded = jnp.asarray(rng.integers(0, 1 << 16, n, dtype=np.uint32))
-
-    def arm(impl, k, key_bound=None):
-        fn = jax.jit(lambda a, b: sort_kv_unstable(
-            a, b, impl=impl, key_bound=key_bound)[0])
-        return _time_amortized(fn, (k, rids), iters=2) * 1e3
-
-    xla_wall = arm("xla", keys)
-    pallas_wall = arm("pallas_interpret", keys)
-    bounded_wall = arm("pallas_interpret", bounded, key_bound=1 << 16)
-    passes = num_radix_passes(None)
-    bounded_passes = num_radix_passes(1 << 16)
-    if not (bounded_passes < passes and bounded_wall < pallas_wall):
-        print(f"ERROR: bounded keys must run fewer passes at lower wall: "
-              f"{bounded_passes}/{passes} passes, "
-              f"{bounded_wall:.0f}/{pallas_wall:.0f} ms", file=sys.stderr)
-        sys.exit(3)
-    # the slot kernel alone (one digit pass; passes are cost-identical,
-    # so the per-row kernel wall is one pass times the row's pass count)
-    kernel_fn = jax.jit(lambda k: radix_pass_slots_pallas(
-        k, shift=0, interpret=True))
-    kernel_wall = _time_amortized(kernel_fn, (keys,), iters=2) * 1e3 * passes
-    unit = kernel_wall / (passes * n / 1e6)
-    speedup = xla_wall / max(pallas_wall, 1e-9)
-    print(f"note: {n} keys kv-sorted: xla {xla_wall:.0f} ms, radix "
-          f"{pallas_wall:.0f} ms/{passes}p (kernel {kernel_wall:.0f} ms), "
-          f"bounded {bounded_wall:.0f} ms/{bounded_passes}p, "
-          f"speedup {speedup:.2f}x, unit {unit:.4f} ms/Mtuple/pass",
-          file=sys.stderr)
-
-    result = {
-        "metric": "radix_sort_speedup",
-        "value": round(speedup, 3),
-        "unit": "xla_over_pallas_wall",
-        "vs_baseline": round(speedup, 3),
-        "size": n,
-        "sort_ms": round(pallas_wall, 1),
-        "sort_xla_ms": round(xla_wall, 1),
-        "sort_kernel_ms": round(kernel_wall, 1),
-        "sort_pass_unit_ms": round(unit, 4),
-        "sort_passes": passes,
-        "sort_bounded_ms": round(bounded_wall, 1),
-        "sort_bounded_passes": bounded_passes,
-        "sortfallback": int(fallbacks),
-    }
-    print(json.dumps(result))
-    _ledger_append(result)
-    if check_baseline:
-        from tpu_radix_join.observability.regress import check_result
-        code, report = check_result(result, check_baseline)
-        print(report, file=sys.stderr)
-        return code
-    return 0
-
-
 def _run_serve_bench(check_baseline=None, queries=20, chaos=False):
     """``--serve-bench [N]``: the resident-service amortization bench.  N
     queries stream through ONE JoinSession on host CPU; query 0 pays mesh
@@ -1774,11 +1619,6 @@ def main():
         # scatter): CPU-sized like --grid-bench — it gates the fused
         # partition kernel's speedup and unit constant, not chip throughput
         sys.exit(_run_partition_bench(check_baseline))
-    if "--sort-bench" in argv:
-        # flat-sort A/B (ops/pallas/radix_sort.py vs lax.sort): CPU-sized
-        # like --grid-bench — it gates the LSD radix kernel's correctness,
-        # pass-skipping, and unit constant, not chip throughput
-        sys.exit(_run_sort_bench(check_baseline))
     if "--recovery-bench" in argv:
         # elastic-recovery A/B (robustness/recovery.py): CPU-sized like
         # --chaos/--grid-bench — it gates kill-1-of-8 partition-level

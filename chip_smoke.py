@@ -40,9 +40,9 @@ TUPLES_PER_NODE = 20_000_000
 SEED = 1234
 ZIPF_THETA = 0.75
 REPO = os.path.dirname(os.path.abspath(__file__))
-#: counters that record which partition and sort implementation ``auto``
-#: resolved to at trace time (ops/radix.py, ops/sorting.py)
-IMPL_COUNTERS = ("PARTPASS", "PARTFALLBACK", "SORTPASS", "SORTFALLBACK")
+#: counters that record which partition implementation ``auto`` resolved
+#: to at trace time (ops/radix.py)
+IMPL_COUNTERS = ("PARTPASS", "PARTFALLBACK")
 
 
 class SmokeFailure(Exception):
@@ -154,7 +154,7 @@ def batch_phase(name: str, nodes: int, outer_kind: str, expected: int,
     check(out_devices == nodes,
           f"{name}: the {nodes}-node join's output sits on {out_devices} "
           f"distinct devices")
-    check(counters["PARTFALLBACK"] == 0 and counters["SORTFALLBACK"] == 0,
+    check(counters["PARTFALLBACK"] == 0,
           f"{name}: auto fell back off the Pallas kernels: {counters}")
 
 
@@ -210,7 +210,6 @@ def run(four_chips: bool) -> dict:
     check(len(devices) >= nodes,
           f"--four-chips needs 4 devices, JAX sees {len(devices)}")
 
-    from tpu_radix_join.ops.sorting import resolve_sort_impl
     from tpu_radix_join.utils.platform import enable_compile_cache
 
     cache = enable_compile_cache()
@@ -218,8 +217,6 @@ def run(four_chips: bool) -> dict:
     print(f"[SMOKE] device_kind={dev.device_kind} devices={len(devices)}",
           flush=True)
     print(f"[SMOKE] compile cache {cache} ({entries} entries before run)",
-          flush=True)
-    print(f"[SMOKE] auto sort impl: {resolve_sort_impl('auto')}",
           flush=True)
 
     gs = TUPLES_PER_NODE * nodes

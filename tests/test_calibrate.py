@@ -164,7 +164,6 @@ def test_v1_shim_and_committed_still_load(tmp_path):
                         for k in committed.constants
                         if k not in ("ici_bytes_per_s",
                                      "partition_pass_unit_ms",
-                                     "radix_sort_pass_unit_ms",
                                      "result_cache_lookup_ms")}}
     path = str(tmp_path / "v1.json")
     with open(path, "w") as f:
@@ -175,10 +174,6 @@ def test_v1_shim_and_committed_still_load(tmp_path):
     assert back.value("partition_pass_unit_ms") == pytest.approx(
         8.0 / committed.value("hbm_gbps"), rel=1e-3)
     assert back.source("partition_pass_unit_ms").startswith("shim:")
-    # v5 shim: the flat-sort pass unit derives from the same bandwidth
-    assert back.value("radix_sort_pass_unit_ms") == pytest.approx(
-        12.0 / committed.value("hbm_gbps"), rel=1e-3)
-    assert back.source("radix_sort_pass_unit_ms").startswith("shim:")
     # v6 shim: the result-cache probe derives from the dispatch floor
     assert back.value("result_cache_lookup_ms") == pytest.approx(
         committed.value("dispatch_floor_ms") / 10.0, rel=1e-3)
@@ -269,7 +264,7 @@ def test_profile_fit_cli_fit_and_diff(tmp_path):
         led.append("bench", _bench_row(0.3, rid=f"b{i}"))
     out = _cli("tools_profile_fit.py", "fit", "--ledger", str(tmp_path))
     assert out.returncode == 0, out.stderr
-    assert "fitted 1/12 constants" in out.stdout
+    assert "fitted 1/11 constants" in out.stdout
     fitted = str(tmp_path / FITTED_PROFILE_BASENAME)
     assert load_profile(fitted).schema_version == 6
     # 0.3 vs committed 0.147 is > 25% -> diff gates

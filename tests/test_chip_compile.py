@@ -5,8 +5,8 @@ Interpret mode never traces the kernels' Mosaic branches, so only these
 compiles show here what the chip's compiler refuses: tiles that overrun
 scoped VMEM, or a kernel body that takes minutes to compile.  Sizes are the
 chip smoke's (20M tuples per side, 40M in the combined sort) and group
-counts the ones the main path uses: 32 network partitions, 256 radix
-digits, 4 destinations.  The topology is described inside a fixture, never
+counts the ones the main path uses: 32 network partitions, 4
+destinations.  The topology is described inside a fixture, never
 at import: only one process at a time may load the TPU library.
 """
 
@@ -61,11 +61,18 @@ def test_partition_slots_compiles(one_chip, num_groups, capacity):
         ids, num_groups=num_groups, capacity=capacity), one_chip, N)
 
 
-@pytest.mark.parametrize("shift", [0, 24])
-def test_radix_pass_compiles(one_chip, shift):
-    from tpu_radix_join.ops.pallas.radix_sort import radix_pass_slots_pallas
-    _compile(lambda keys: radix_pass_slots_pallas(keys, shift=shift),
-             one_chip, 2 * N)
+@pytest.mark.parametrize("wrapper", ["kv", "lex2"])
+def test_sort_wrapper_compiles(one_chip, wrapper):
+    """The sort wrappers compile to XLA's own sort, not to a kernel."""
+    from tpu_radix_join.ops.sorting import sort_kv_unstable, sort_lex_unstable
+    fn = {"kv": lambda k, v: sort_kv_unstable(k, v),
+          "lex2": lambda hi, lo, v: sort_lex_unstable(hi, lo, v,
+                                                      num_keys=2)}[wrapper]
+    lanes = 2 if wrapper == "kv" else 3
+    args = [jax.ShapeDtypeStruct((N,), jnp.uint32, sharding=one_chip)
+            for _ in range(lanes)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert " sort(" in text and "custom-call" not in text
 
 
 def test_merge_scan_partitions_compiles(one_chip):

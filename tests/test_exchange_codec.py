@@ -317,17 +317,16 @@ def test_planner_prices_codec_and_explains_choice():
 
 def test_plan_schema_v4_and_older_back_compat():
     from tpu_radix_join.planner.plan import PLAN_SCHEMA_VERSION, JoinPlan
-    assert PLAN_SCHEMA_VERSION == 5
+    assert PLAN_SCHEMA_VERSION == 6
     doc = JoinPlan(engine="incore", exchange_codec="pack",
                    exchange_stages=4,
                    predicted_terms={"shuffle": 1.5}).to_dict()
     again = JoinPlan.from_dict(doc)
     assert again.exchange_codec == "pack" and again.exchange_stages == 4
     assert again.predicted_terms == {"shuffle": 1.5}
-    # a v4 file (pre-sort-arm) has no sort_impl: runtime auto on load
-    v4 = {k: v for k, v in doc.items() if k != "sort_impl"}
-    v4["schema_version"] = 4
-    assert JoinPlan.from_dict(v4).sort_impl == "auto"
+    # a v5 file carries the retired sort_impl: ignored on load
+    v5 = {**doc, "sort_impl": "xla", "schema_version": 5}
+    assert JoinPlan.from_dict(v5).to_dict() == {**doc, "schema_version": 5}
     # a v3 file (pre-audit) has no predicted_terms: empty table on load
     v3 = {k: v for k, v in doc.items() if k != "predicted_terms"}
     v3["schema_version"] = 3

@@ -206,7 +206,8 @@ def test_merge_full_inside_shard_map():
     cannot run under shard_map: the HLO interpreter evaluates its top-level
     ops on the tile's varying mesh axes and rejects mixing them with
     constants (asserted below so a JAX upgrade that lifts it is noticed;
-    ops/pallas/radix_sort.py shows the in-kernel workaround).  Compiled
+    the workaround is to read the tile inside the ``pl.when`` phase
+    bodies).  Compiled
     Pallas traces its kernel outside the mesh."""
     import jax
     from jax.sharding import PartitionSpec as P

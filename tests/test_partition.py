@@ -144,19 +144,6 @@ def test_mosaic_branch_matches_interpret_branch(mosaic_branch_interpreted,
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("shift", [0, 8, 24])
-def test_radix_pass_mosaic_branch_matches_interpret_branch(
-        mosaic_branch_interpreted, shift):
-    import jax
-
-    from tpu_radix_join.ops.pallas.radix_sort import radix_pass_slots_pallas
-    keys = jnp.asarray(np.random.default_rng(shift).integers(
-        0, 1 << 32, 100_003, dtype=np.uint64).astype(np.uint32))
-    mosaic = jax.jit(lambda k: radix_pass_slots_pallas(k, shift=shift))(keys)
-    interp = radix_pass_slots_pallas(keys, shift=shift, interpret=True)
-    np.testing.assert_array_equal(np.asarray(mosaic), np.asarray(interp))
-
-
 def test_kernel_rejects_bad_geometry():
     ids = jnp.zeros((16,), jnp.uint32)
     with pytest.raises(ValueError, match=f"> {MAX_PARTITIONS + 1}"):

@@ -61,25 +61,6 @@ lower-is-better, alongside the ``PARTFALLBACK`` counter (silent degrades
 to the XLA sort path; on a TPU backend more of them means the fused
 kernel stopped being auto-selected).
 
-A ``--sort-bench`` BENCH json gates the flat-sort A/B
-(ops/pallas/radix_sort.py LSD radix sort vs the lax.sort emitter):
-
-    {"metric": "radix_sort_speedup", "value": 1.42, "size": 262144,
-     "sort_ms": 22.5, "sort_xla_ms": 32.0, "sort_kernel_ms": 14.1,
-     "sort_pass_unit_ms": 0.1134, "sort_passes": 4,
-     "sort_bounded_ms": 11.3, "sort_bounded_passes": 2, "sortfallback": 0}
-
-The headline ``value`` is the wall speedup (xla arm over radix arm,
-higher is better; expected < 1 when the radix arm runs interpreted on
-host CPU).  ``sort_ms`` / ``sort_xla_ms`` / ``sort_kernel_ms`` /
-``sort_bounded_ms`` are walls, ``sort_pass_unit_ms`` is the reduced
-ms/Mtuple/digit-pass constant the profile fitter recovers, and
-``sort_passes`` / ``sort_bounded_passes`` count LSD digit passes (more
-passes means the key-bound pass skip stopped firing) — all pinned
-lower-is-better, alongside the ``SORTFALLBACK`` counter (it reads 0
-since the sort's ``auto`` is lax.sort itself, so any nonzero value is a
-regression).
-
 A ``--recovery-bench`` BENCH json gates the elastic-recovery A/B
 (robustness/membership.py + recovery.py — kill-1-of-8 partition-level
 recovery vs the cold full restart it replaces):

@@ -1,17 +1,15 @@
-"""Rule ``sort-bypass``: hot sorts must route through the sort switch.
+"""Rule ``sort-bypass``: hot sorts must route through ``ops/sorting.py``.
 
-PR 12 centralised every hot reorder behind ``ops/sorting.py``
-(``sort_unstable`` / ``sort_kv_unstable`` / ``sort_lex_unstable``) so
-the xla-vs-Pallas radix arm is one trace-time decision; PR 10 did the
-same for partitioning.  A direct ``jax.lax.sort`` / ``jnp.sort`` /
-``jnp.argsort`` call anywhere else silently bypasses the switch: the
-site never sees the Pallas arm, never ticks SORTPASS, sits outside the
-``trj.sort`` scope that names the sort's device time, and the planner's
-``plan_sort`` prediction stops matching what traces.
+Every hot reorder goes through ``sort_unstable`` / ``sort_kv_unstable``
+/ ``sort_lex_unstable``, which run ``lax.sort`` under the ``trj.sort``
+scope.  A direct ``jax.lax.sort`` / ``jnp.sort`` / ``jnp.argsort`` call
+anywhere else sits outside that scope, so its device time escapes the
+per-stage sort time that reads it (and ``jnp.sort``/``argsort`` are
+stable sorts, ~2x the cost).
 
 Host-side ``np.sort``/``np.argsort`` are NOT flagged — numpy on host
-arrays is the oracle/verification idiom, not a device sort.  The sort
-switch's own module and the Pallas kernels are the allowed homes.
+arrays is the oracle/verification idiom, not a device sort.  The
+wrappers' own module and the Pallas kernels are the allowed homes.
 """
 
 from __future__ import annotations
@@ -24,7 +22,7 @@ from tpu_radix_join.analysis.core import (Finding, Repo, dotted_name, rule)
 ALLOWED_FILES = ("tpu_radix_join/ops/sorting.py",)
 ALLOWED_PREFIXES = ("tpu_radix_join/ops/pallas/",)
 
-#: dotted call spellings that bypass the switch
+#: dotted call spellings that bypass the wrappers
 SORT_CALLS = {
     "jax.lax.sort", "lax.sort",
     "jnp.sort", "jnp.argsort", "jnp.lexsort",
@@ -36,7 +34,7 @@ HOST_ROOTS = {"np", "numpy"}
 
 @rule("sort-bypass",
       "direct lax.sort/jnp.sort/argsort outside ops/sorting.py "
-      "bypasses the PR 12 sort switch",
+      "escapes the trj.sort scope",
       token="sort")
 def check(repo: Repo) -> List[Finding]:
     out: List[Finding] = []
@@ -52,8 +50,8 @@ def check(repo: Repo) -> List[Finding]:
                 out.append(Finding(
                     rule="sort-bypass", path=src.rel, line=node.lineno,
                     key=name,
-                    message=(f"direct {name} call bypasses the "
-                             f"ops/sorting.py sort switch — use "
+                    message=(f"direct {name} call escapes the trj.sort "
+                             f"scope of ops/sorting.py — use "
                              f"sort_unstable/sort_kv_unstable (or add a "
                              f"baseline entry with a reason)")))
             elif (isinstance(node.func, ast.Attribute)
@@ -70,7 +68,7 @@ def check(repo: Repo) -> List[Finding]:
                     out.append(Finding(
                         rule="sort-bypass", path=src.rel, line=node.lineno,
                         key=f".{node.func.attr}()",
-                        message=(f".{node.func.attr}() reorder bypasses "
-                                 f"the ops/sorting.py sort switch — use "
-                                 f"sort_kv_unstable")))
+                        message=(f".{node.func.attr}() reorder escapes "
+                                 f"the trj.sort scope of ops/sorting.py — "
+                                 f"use sort_kv_unstable")))
     return out

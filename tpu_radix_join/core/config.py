@@ -110,17 +110,7 @@ class JoinConfig:
     #              runs it through the Pallas interpreter: CPU tier-1
     #              parity tests and host-mesh benches).
     partition_impl: str = "auto"
-    # Sort implementation behind every hot reorder (ops/sorting.py:
-    # merge_count presort, bucket build/probe, verify xor-fold, grouped
-    # codec — all inherit it with zero call-site edits):
-    #   "auto"   — lax.sort, for every size, shape and backend: on a
-    #              TPU v5e it beats the radix sort at every size measured
-    #              (a 20M x 20M join: 75 ms against 2647 ms; PERF.md §5).
-    #   "xla"    — lax.sort, the same as "auto".
-    #   "pallas" / "pallas_interpret" — force the Pallas LSD radix sort
-    #              (ops/pallas/radix_sort.py) for every eligible 1-D uint32
-    #              sort (interpret = the Pallas interpreter: CPU tier-1
-    #              parity tests and host-mesh benches).
+    # "auto" and "xla" both mean lax.sort, the only sort (ops/sorting.py).
     sort_impl: str = "auto"
 
     # --- policies --------------------------------------------------------------
@@ -233,11 +223,10 @@ class JoinConfig:
             raise ValueError(
                 f"unknown partition impl {self.partition_impl!r} (expected "
                 "'auto', 'sort', 'pallas', or 'pallas_interpret')")
-        if self.sort_impl not in ("auto", "xla", "pallas",
-                                  "pallas_interpret"):
+        if self.sort_impl not in ("auto", "xla"):
             raise ValueError(
                 f"unknown sort impl {self.sort_impl!r} (expected "
-                "'auto', 'xla', 'pallas', or 'pallas_interpret')")
+                "'auto' or 'xla')")
         if self.max_retries < 0:
             raise ValueError("max_retries must be >= 0")
         if self.fallback not in ("none", "chunked"):

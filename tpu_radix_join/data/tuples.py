@@ -207,9 +207,7 @@ def effective_key_bits(key_bound: Optional[int], fanout_bits: int = 0,
     dropped by the caller (the wire codec shifts them out before packing).
     This is the single source of truth for every bounds-aware width
     decision: the packed exchange codec (``make_wire_spec``) sizes its
-    field widths from it, and the Pallas LSD radix sort
-    (ops/pallas/radix_sort.py) skips the digit passes it proves constant
-    — a 16-bit-bounded key needs 2 of the 4 uint32 passes.
+    field widths from it.
     """
     if not 0 <= fanout_bits < key_bits:
         raise ValueError(

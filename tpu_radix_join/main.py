@@ -90,17 +90,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "'sort' forces the sort-based scatter; 'pallas"
                         "_interpret' runs the kernel interpreted (CPU "
                         "parity/bench)")
-    p.add_argument("--sort-impl",
-                   choices=["auto", "xla", "pallas", "pallas_interpret"],
-                   default="auto",
-                   help="sort implementation behind every hot reorder "
-                        "(ops/sorting.py): 'auto' and 'xla' are lax.sort, "
-                        "which beats the radix sort at every size measured "
-                        "on a TPU v5e (a 20M x 20M join: 75 ms against "
-                        "2647 ms); 'pallas' forces the Pallas LSD radix "
-                        "sort (ops/pallas/radix_sort.py) for 1-D uint32 "
-                        "sorts; 'pallas_interpret' runs that kernel "
-                        "interpreted (CPU parity/bench)")
     p.add_argument("--cpu-fallback", action="store_true",
                    help="if device/mesh init fails, rebuild the engine over "
                         "host CPU devices (loud [DEGRADE] warning) instead "
@@ -1376,7 +1365,6 @@ def main(argv=None) -> int:
         exchange_codec=args.exchange_codec,
         exchange_stages=args.exchange_stages,
         partition_impl=args.partition_impl,
-        sort_impl=args.sort_impl,
     )
 
     meas = Measurements(node_id=jax.process_index(), num_nodes=nodes)

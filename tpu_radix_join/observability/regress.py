@@ -102,18 +102,6 @@ _LOWER_BETTER_SUBSTRINGS = ("rejection_rate", "miss_rate", "degraded_rate",
                             "partition_ms", "partition_kernel_ms",
                             "partition_sort_ms", "partition_unit_ms",
                             "partfallback",
-                            # flat-sort A/B tags (--sort-bench): both arms'
-                            # walls, the radix slot-kernel wall, the reduced
-                            # per-digit-pass unit, and the pass counts are
-                            # all times or work counts (more LSD passes per
-                            # sort means the key-bound pass skip stopped
-                            # firing); SORTFALLBACK reads 0 since ``auto``
-                            # is lax.sort itself, so any nonzero value is
-                            # a regression
-                            "sort_ms", "sort_xla_ms", "sort_kernel_ms",
-                            "sort_pass_unit_ms", "sort_passes",
-                            "sort_bounded_ms", "sort_bounded_passes",
-                            "sortfallback",
                             # elastic-recovery tags (--recovery-bench and
                             # the membership counters): more ranks lost,
                             # a longer detect→recompute→splice wall, more
@@ -180,7 +168,7 @@ _COST_TAGS = {"JTOTAL", "JPROC", "JHIST", "JMPI", "JCOMPILE", "SWINALLOC",
               # refusals (service/session.py)
               "QWAIT", "QSERVE", "QTABLE", "QUPDATE", "QFINISH", "QSTALE",
               "VFAIL", "VREPAIR",
-              "PARTPASS", "SORTPASS",
+              "PARTPASS",
               "MWINBYTES", "PACKRATIO",
               "JXAUDIT",
               # straggler hedging: more hedges per round means more ranks

@@ -4,8 +4,7 @@ Every stage of the join names its device work with a ``jax.named_scope``
 inside the function that implements it, so every caller inherits the
 name:
 
-* ``trj.sort``: the sorts of ``ops/sorting.py`` (radix passes, their
-  permutation scatters, ``lax.sort``);
+* ``trj.sort``: the ``lax.sort`` calls of ``ops/sorting.py``;
 * ``trj.merge_scan``: the merge count of ``ops/merge_count.py`` and its
   Pallas scan;
 * ``trj.partition``: histograms, partition ids, the radix partitioning

@@ -70,8 +70,6 @@ from tpu_radix_join.operators import skew
 from tpu_radix_join.operators.local_partitioning import local_partition
 from tpu_radix_join.ops.radix import (local_histogram, scatter_to_blocks,
                                       install_partition_observer)
-from tpu_radix_join.ops.sorting import (install_sort_observer,
-                                        set_default_sort_impl)
 from tpu_radix_join.parallel.mesh import make_hierarchical_mesh, make_mesh
 from tpu_radix_join.parallel.network_partitioning import (network_partition,
                                                           receive_checksums)
@@ -208,12 +206,6 @@ class HashJoin:
         # donates this one for the lifetime of the process
         if measurements is not None:
             install_partition_observer(measurements)
-            install_sort_observer(measurements)
-        # the sort primitives are reached from deep inside ops/ with no
-        # config in scope (that is the point of the ops/sorting switch),
-        # so the configured impl binds process-wide; join entry points
-        # re-assert it before tracing in case another engine rebound it
-        set_default_sort_impl(config.sort_impl)
         # cooperative cancellation hook (service/deadline.py): an optional
         # ``callable(phase: str)`` consulted between pipeline phases; it
         # raises (e.g. DeadlineExceeded) to cancel the query between
@@ -1764,7 +1756,6 @@ class HashJoin:
         instead of raising.  Successful joins record their realized
         partitions into ``self.partition_manifest`` when one is attached.
         """
-        set_default_sort_impl(self.config.sort_impl)
         if not self.elastic and self.partition_manifest is None:
             return self._join_arrays_inner(r, s, repeats)
         if (self.membership is not None and self.partition_manifest is not None
@@ -2788,7 +2779,6 @@ class HashJoin:
         """Full join with materialized rid pairs (vs. the count-only default —
         the same distinction as the reference's probe_kernel_eth count-only
         path vs. probe_match_rate, kernels.cu:314-411)."""
-        set_default_sort_impl(self.config.sort_impl)
         n = self.config.num_nodes
         if r.size % n or s.size % n:
             raise ValueError("relation sizes must divide the mesh size")

@@ -184,15 +184,11 @@ def reorder_by_partition(
         out = jax.tree.map(scatter, batch)
         hist = hist_x[:num_partitions]
         return out, scatter(pid), hist, exclusive_cumsum(hist)
-    # kv-sort through the ops/sorting switch instead of argsort + gather:
-    # the payload lanes travel with their key in one fused sort (a
-    # profiled 3x win over argsort+gather on v5e — see scatter_to_blocks),
-    # and the site inherits the xla-vs-pallas arm for free.  The key
-    # bound (ids are < num_partitions + 1, invalid rows routed to exactly
-    # num_partitions) lets the radix arm skip digit passes.
+    # kv-sort instead of argsort + gather: the payload lanes travel with
+    # their key in one fused sort (a profiled 3x win over argsort+gather
+    # on v5e — see scatter_to_blocks)
     leaves, treedef = jax.tree.flatten(batch)
-    sorted_lanes = sort_kv_unstable(sort_key, *leaves, pid,
-                                    key_bound=num_partitions + 1)
+    sorted_lanes = sort_kv_unstable(sort_key, *leaves, pid)
     key_s = sorted_lanes[0]
     out = jax.tree.unflatten(treedef, sorted_lanes[1:-1])
     # run bounds over the already-sorted keys replace the separate

@@ -161,13 +161,6 @@ PARTFALLBACK = "PARTFALLBACK"  # partition/histogram auto-select fell back to
                            # the XLA sort path (Pallas unavailable or fanout
                            # past MAX_PARTITIONS) — the silent-degrade signal;
                            # more of these on a TPU backend is a regression
-SORTPASS = "SORTPASS"      # Pallas LSD radix sorts selected at trace time
-                           # (ops/sorting.py resolve_sort_impl); one per
-                           # traced sort site, like PARTPASS
-SORTFALLBACK = "SORTFALLBACK"  # nothing ticks it: the sort's ``auto`` is
-                           # lax.sort, with no arm to fall back from; it
-                           # reads 0 and stays because the benchmark's
-                           # ``fallbacks`` check and chip_smoke.py read it
 FAILOVER = "FAILOVER"      # fleet queries failed over to another worker after
                            # the routed worker died mid-query (service/fleet.py)
 REPLAYN = "REPLAYN"        # journal intents replayed (failover retries plus

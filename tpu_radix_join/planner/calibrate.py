@@ -25,10 +25,6 @@ Per-constant stage models (sample extraction):
   * ``partition_pass_unit_ms`` — ``--partition-bench`` rows: the fused
     arm's kernel wall inverts over two passes at the row's element count
     (ops/pallas/partition.py makes exactly two streaming passes).
-  * ``radix_sort_pass_unit_ms`` — ``--sort-bench`` rows: the Pallas LSD
-    radix arm's slot-kernel wall inverts over the digit passes the row's
-    key bound ran (ops/pallas/radix_sort.py skips passes the bound
-    proves constant, so the row carries its actual pass count).
   * anything — ``kind="obs"`` rows carry a pre-reduced
     ``{"constant": ..., "value": ...}`` observation (the extension point
     for dedicated probes).
@@ -54,11 +50,6 @@ from tpu_radix_join.planner.profile import (SORT_REF_ELEMS, DeviceProfile,
 #: (cost_model.py's stage models; ``overlap`` is a negative credit and
 #: ``probe``/``sort`` both ride the sort emitter's unit)
 TERM_TO_CONSTANT = {
-    # the sort term rides plan_sort's chosen arm — xla rows price it by
-    # the stage unit, pallas rows by radix_sort_pass_unit_ms; staleness
-    # attribution keeps the stage unit as the default blame (the xla arm
-    # is the one the committed evidence fitted; a drifting pallas row
-    # shows up in --sort-bench refits instead)
     "sort": "sort_stage_unit_ms",
     "probe": "sort_stage_unit_ms",
     "scan": "hbm_gbps",
@@ -80,11 +71,6 @@ BENCH_SORT_METRIC = "single_chip_join_throughput"
 #: invert directly to ms per million tuples per pass (the kernel makes
 #: two passes, ops/pallas/partition.py)
 BENCH_PARTITION_METRIC = "partition_fused_speedup"
-
-#: --sort-bench A/B rows: the Pallas radix arm's kernel wall inverts to
-#: ms per million tuples per digit pass (the row carries the pass count
-#: its key bound actually ran, so bounded rows fit the same unit)
-BENCH_RADIX_SORT_METRIC = "radix_sort_speedup"
 
 #: runs at or below this global size are pure dispatch floor
 SMALL_RUN_ELEMS = 1 << 16
@@ -158,26 +144,6 @@ def _partition_unit_from_bench(row: dict) -> Optional[Sample]:
     return None
 
 
-def _radix_sort_unit_from_bench(row: dict) -> Optional[Sample]:
-    """Invert a --sort-bench row to ms/Mtuple/pass: the Pallas arm's slot
-    kernel wall over the digit passes the row's key bound ran (the bench
-    also publishes the reduced ``sort_pass_unit_ms`` tag; recomputing
-    from the primary measurement keeps the fit independent of the
-    reduction)."""
-    if row.get("metric") != BENCH_RADIX_SORT_METRIC:
-        return None
-    size = int(row.get("size") or 0)
-    passes = int(row.get("sort_passes") or 0)
-    kernel_ms = float(row.get("sort_kernel_ms") or 0.0)
-    rid = str(row.get("run_id", "?"))
-    if size > 0 and passes > 0 and kernel_ms > 0:
-        return Sample(kernel_ms / (passes * size / 1e6), rid)
-    unit = float(row.get("sort_pass_unit_ms") or 0.0)
-    if unit > 0:
-        return Sample(unit, rid)
-    return None
-
-
 def collect_samples(rows: List[dict]) -> Dict[str, List[Sample]]:
     """Constant -> samples, pooled across every row kind that carries
     evidence for it.  Rows that lack a given signal simply contribute
@@ -198,9 +164,6 @@ def collect_samples(rows: List[dict]) -> Dict[str, List[Sample]]:
             s = _partition_unit_from_bench(row)
             if s is not None:
                 out.setdefault("partition_pass_unit_ms", []).append(s)
-            s = _radix_sort_unit_from_bench(row)
-            if s is not None:
-                out.setdefault("radix_sort_pass_unit_ms", []).append(s)
         elif kind == "run":
             times = row.get("times_us") or {}
             counters = row.get("counters") or {}

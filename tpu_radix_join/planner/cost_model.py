@@ -198,58 +198,6 @@ def plan_partition(profile: DeviceProfile, elems: int,
               f"scatter loop {sort_arm:.2f} ms beats fused {fused:.2f} ms"))
 
 
-def radix_sort_ms(profile: DeviceProfile, elems: int, passes: int,
-                  lanes: int = 2) -> float:
-    """LSD radix-sort cost (ops/pallas/radix_sort.py): each digit pass
-    runs the slot kernel — priced per tuple by ``radix_sort_pass_unit_ms``
-    (the key lane streams through both grid phases plus the slot
-    writeback) — and then moves every lane across HBM once through the
-    collision-free permutation scatter.  Linear in ``passes``, which is
-    how the bounded-key pass skip shows up in the plan."""
-    if elems <= 0 or passes <= 0:
-        return 0.0
-    return passes * (profile.value("radix_sort_pass_unit_ms") * elems / 1e6
-                     + hbm_pass_ms(profile, elems * lanes * LANE_BYTES))
-
-
-@dataclasses.dataclass(frozen=True)
-class SortPlan:
-    """The cost model's sort-engine decision: the Pallas LSD radix sort
-    vs the XLA sort emitter, with both arms' prices kept for the explain
-    table (mirrors :class:`PartitionPlan` for destination grouping)."""
-
-    impl: str               # "xla": what ``auto`` runs
-    sort_ms: float          # the chosen arm
-    pallas_ms: float        # bounded LSD digit passes + per-lane scatters
-    xla_ms: float           # the stage-model lax.sort arm
-    passes: int             # digit passes the radix arm would run
-    note: str = ""
-
-
-def plan_sort(profile: DeviceProfile, elems: int, lanes: int = 2,
-              key_bound: Optional[int] = None, key_bits: int = 32,
-              lane_factor: float = 1.0, rows: int = 1) -> SortPlan:
-    """Price both sort arms and bind ``xla``, the arm ops/sorting's
-    ``auto`` picks at every size: on the chip ``lax.sort`` beats the
-    radix sort everywhere measured, so the plan never binds an arm that
-    ``auto`` would not pick.  The radix arm stays priced for the explain
-    table, its pass count from the workload's static key bound through
-    the same :func:`~tpu_radix_join.ops.pallas.radix_sort.
-    num_radix_passes` rule the kernel itself skips passes by, so a
-    16-bit-bounded key is priced at 2 passes, not 4; batched
-    (``rows > 1``) sorts are ones the 1-D kernel cannot express."""
-    from tpu_radix_join.ops.pallas.radix_sort import num_radix_passes
-    xla = sort_ms(profile, elems, lane_factor, rows)
-    passes = num_radix_passes(key_bound, key_bits)
-    pal = radix_sort_ms(profile, elems, passes, lanes)
-    note = (f"batched {rows}-row sort: the radix kernel is 1-D only"
-            if rows > 1 else
-            f"lax.sort {xla:.2f} ms; forced {passes}-pass radix "
-            f"{pal:.2f} ms")
-    return SortPlan(impl="xla", sort_ms=xla, pallas_ms=pal, xla_ms=xla,
-                    passes=passes, note=note)
-
-
 def network_fanout_bits(w: Workload) -> int:
     """Network radix bits: at least enough partitions to cover the mesh,
     at most the default 32-way fanout, and never more partitions than
@@ -420,29 +368,17 @@ def enumerate_strategies(profile: DeviceProfile,
             add("incore_fused_sort_narrow", False,
                 {"sort": 0.0}, note=narrow_why)
             continue
-        # the sort term rides plan_sort's chosen engine arm: the narrow
-        # discipline sorts one packed lane whose word mixes key and rid
-        # bits (the static key bound no longer bounds it — worst-case
-        # passes), the full discipline sorts the raw key lane(s) so the
-        # workload's bound skips radix passes
-        splan = plan_sort(
-            profile, union,
-            lanes=(1 if key_mode == "narrow" else w.lanes),
-            key_bound=(None if key_mode == "narrow" else w.key_bound),
-            key_bits=w.key_bits, lane_factor=lane_factor)
-        sort = splan.sort_ms
-        sort_note = f"sort arm: {splan.note}"
+        sort = sort_ms(profile, union, lane_factor)
         add(f"incore_fused_sort_{key_mode}", key_ok and fits,
             {"sort": sort, "scan": scan, **xch,
              "dispatch": amortized_dispatch(PROGRAMS["fused"])},
-            note=key_why or mem_note or sort_note)
+            note=key_why or mem_note)
         add(f"incore_split_sort_{key_mode}", key_ok and fits,
             {"sort": sort, "scan": scan, **xch,
              "dispatch": amortized_dispatch(PROGRAMS["split_sort"],
                                             pipelinable=False)},
             note=(key_why or mem_note
-                  or f"{sort_note}; pays one dispatch floor per split "
-                     f"program"))
+                  or "pays one dispatch floor per split program"))
 
     # two-level bucket discipline: the second radix pass groups tuples by
     # destination bucket — priced by plan_partition as the cheaper of the

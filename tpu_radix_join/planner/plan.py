@@ -18,20 +18,18 @@ from tpu_radix_join.planner.cost_model import (StrategyCost, Workload,
                                                enumerate_strategies,
                                                network_fanout_bits,
                                                pick_chunk_tuples,
-                                               plan_exchange, plan_sort,
-                                               wide_sort_factor)
+                                               plan_exchange)
 from tpu_radix_join.planner.profile import DeviceProfile
 
 # v2 adds ``grid_pipeline`` (the chunked engine's pipelined/synchronous
 # knob); v3 adds ``exchange_codec``/``exchange_stages`` (the bit-packed
 # wire codec and staged all_to_all); v4 adds ``predicted_terms`` (the
 # winning row's per-term ms breakdown, the predicted half of the
-# plan-vs-actual audit — planner/audit.py); v5 adds ``sort_impl`` (the
-# sort-engine arm plan_sort priced for the winning row: the Pallas LSD
-# radix sort vs the XLA sort emitter).  Older files load with the
-# fields' defaults ("auto" pipeline, "off" codec, fused exchange, empty
-# term table, "auto" sort).
-PLAN_SCHEMA_VERSION = 5
+# plan-vs-actual audit — planner/audit.py); v5 added ``sort_impl``, which
+# v6 drops with the radix sort it chose against.  Older files load with
+# the fields' defaults ("auto" pipeline, "off" codec, fused exchange,
+# empty term table), and a v5 file's ``sort_impl`` is ignored.
+PLAN_SCHEMA_VERSION = 6
 
 
 class PlanError(ValueError):
@@ -70,12 +68,6 @@ class JoinPlan:
     grid_pipeline: str = "auto"          # chunked engine: "off"|"on"|"auto"
     exchange_codec: str = "off"          # wire codec: "off" | "pack"
     exchange_stages: int = 1             # 1 = fused all_to_all, k>1 staged
-    #: the sort-engine arm for the winning row's flat sorts
-    #: (cost_model.plan_sort): "pallas" binds the LSD radix kernel,
-    #: "xla" the lax.sort emitter, "auto" leaves the per-site runtime
-    #: select in charge (strategies whose sorts the 1-D kernel cannot
-    #: express anyway — batched bucket sorts, the chunked grid)
-    sort_impl: str = "auto"
     pipeline_repeats: bool = False
     strategy: str = ""
     predicted_ms: float = 0.0
@@ -92,6 +84,7 @@ class JoinPlan:
     @classmethod
     def from_dict(cls, doc: dict) -> "JoinPlan":
         doc = dict(doc)
+        doc.pop("sort_impl", None)        # schema v5's retired field
         version = int(doc.get("schema_version", 1))
         if version > PLAN_SCHEMA_VERSION:
             raise PlanError(
@@ -133,7 +126,6 @@ class JoinPlan:
             "measure_phases": not self.fused,
             "exchange_codec": self.exchange_codec,
             "exchange_stages": self.exchange_stages,
-            "sort_impl": self.sort_impl,
         }
 
 
@@ -227,19 +219,8 @@ def plan_join(profile: DeviceProfile, workload: Workload,
         key_range = "narrow" if narrow else "full"
         if workload.key_bits == 64:
             key_range = "auto"     # wide keys have no range discipline
-        # re-price the winning row's sort with the same geometry
-        # enumerate_strategies used, and bind the chosen engine arm so
-        # the driver forces it instead of re-deciding per site
-        full_factor = (wide_sort_factor(profile) if workload.key_bits == 64
-                       else profile.value("full_range_sort_factor"))
-        splan = plan_sort(
-            profile, workload.union_per_node,
-            lanes=(1 if narrow else workload.lanes),
-            key_bound=(None if narrow else workload.key_bound),
-            key_bits=workload.key_bits,
-            lane_factor=(1.0 if narrow else full_factor))
         plan = JoinPlan(engine="incore", fused=fused, key_range=key_range,
-                        sort_impl=splan.impl, **kw)
+                        **kw)
         if not fused:
             # the split cannot pipeline (fence per program)
             plan = dataclasses.replace(plan, pipeline_repeats=False)
@@ -336,12 +317,6 @@ def explain_table(costs: List[StrategyCost],
                 f"stages={chosen.exchange_stages} "
                 f"({'fused' if chosen.exchange_stages <= 1 else 'staged'} "
                 f"all_to_all)")
-            lines.append(
-                f"sort: impl={chosen.sort_impl} "
-                + {"pallas": "(LSD radix kernel, ops/pallas/radix_sort.py)",
-                   "xla": "(lax.sort emitter)"}.get(
-                       chosen.sort_impl,
-                       "(runtime auto-select per sort site)"))
     if critpath and critpath.get("bound_ms") is not None:
         wf = critpath.get("wait_fraction")
         lines.append(

@@ -43,13 +43,8 @@ from typing import Dict, Optional
 # the kernel makes two passes over the ids and the lanes cross HBM twice).
 # v1-v3 profiles load through a shim deriving it from the cited hbm_gbps
 # (8 B of ids traffic per tuple per pass at streaming bandwidth).
-# v5 adds ``radix_sort_pass_unit_ms`` — ms per million tuples per DIGIT
-# pass of the Pallas LSD radix sort's slot kernel
-# (ops/pallas/radix_sort.py; per digit pass the kernel streams the key
-# lane twice and writes the slot permutation once; the per-lane scatters
-# are priced separately from hbm_gbps).  v1-v4 profiles load through a
-# shim deriving it as 12/hbm_gbps; calibrate.py re-fits it from
-# ``--sort-bench`` ledger rows with provenance.
+# v5 added the per-digit-pass price of a Pallas radix sort the pipeline
+# no longer has; a file that still carries it loads, and nothing reads it.
 # v6 adds ``result_cache_lookup_ms`` — the host-side price of one
 # fingerprint + LRU probe of the serving result cache
 # (service/resultcache.py; the planner's serve_cached strategy row is
@@ -93,15 +88,6 @@ REQUIRED_CONSTANTS = (
     # to 8.0 / hbm_gbps at load (4 B read + 4 B written per tuple per pass
     # at the profile's streaming bandwidth).
     "partition_pass_unit_ms",
-    # Pallas LSD radix sort: ms per million tuples per digit pass of the
-    # slot kernel (cost_model.radix_sort_ms charges
-    # unit * Mtuples * passes + one per-lane scatter pass per digit; the
-    # pass count shrinks with the workload's key bound via
-    # data/tuples.effective_key_bits).  Schema v5; older profiles are
-    # shimmed to 12.0 / hbm_gbps at load (the kernel reads the 4 B key
-    # lane in both phases and writes 4 B of slots).  calibrate.py fits it
-    # from --sort-bench ledger rows (sort_kernel_ms / passes / Mtuples).
-    "radix_sort_pass_unit_ms",
     # serving result cache: ms per fingerprint + LRU probe on the host
     # (service/resultcache.py — sha256 over the canonical request spec
     # plus one OrderedDict move-to-end; no device work at all).  The
@@ -255,19 +241,6 @@ def load_profile(name_or_path: str = "v5e_lite") -> DeviceProfile:
                     "source": ("shim:derived from hbm_gbps "
                                f"(schema v{version} profile; "
                                f"{entry.get('source', 'uncited')})")}
-        if version < 5 and "radix_sort_pass_unit_ms" not in constants:
-            # schema v1-v4 shim: the radix-sort cost arm (schema v5) reads
-            # radix_sort_pass_unit_ms; derive it from the cited hbm_gbps —
-            # per digit pass the slot kernel streams the 4 B key lane in
-            # both grid phases and writes 4 B of slots, 12 B/tuple, so a
-            # million tuples cost 12/B ms at B GB/s.
-            entry = constants.get("hbm_gbps")
-            if isinstance(entry, dict) and entry.get("value"):
-                constants["radix_sort_pass_unit_ms"] = {
-                    "value": round(12.0 / float(entry["value"]), 5),
-                    "source": ("shim:derived from hbm_gbps "
-                               f"(schema v{version} profile; "
-                               f"{entry.get('source', 'uncited')})")}
         if version < 6 and "result_cache_lookup_ms" not in constants:
             # schema v1-v5 shim: the serve_cached strategy row (schema v6)
             # reads result_cache_lookup_ms; derive it from the cited
@@ -405,8 +378,7 @@ def calibrate(base: Optional[DeviceProfile] = None,
     device.  The elementwise pass moves 2^27
     uint32 each way (1 GiB of traffic), so each dispatch's kernel outlasts
     the sub-ms dispatch floor and the pass measures bandwidth, not the
-    floor.  ``radix_sort_pass_unit_ms`` is timed on the Pallas slot kernel
-    itself where the backend has one.  Sources are tagged
+    floor.  Sources are tagged
     ``calibrate:<benchmark>`` so a calibrated profile is distinguishable
     from the committed chip tables at a glance.
     """
@@ -439,28 +411,9 @@ def calibrate(base: Optional[DeviceProfile] = None,
     dt = timed(jax.jit(lambda a: jax.lax.sort(a, is_stable=False)), keys)
     unit = dt * 1e3 / (sort_elems / SORT_REF_ELEMS) / sort_stage_units(
         sort_elems)
-    # the citation RECORDS THE MEASURED IMPL: sort_stage_unit_ms models
-    # the XLA sort emitter specifically, and with the ops/sorting switch
-    # in play a probe that silently routed through the Pallas radix sort
-    # would cross-attribute radix passes to the stage model (and vice
-    # versa for a fitted radix_sort_pass_unit_ms).  lax.sort is called
-    # directly here — impl pinned, not resolved — and the tag says so.
     updates["sort_stage_unit_ms"] = {
         "value": round(unit, 5),
         "source": "calibrate:flat_sort impl=xla(jax.lax.sort)"}
-    # radix slot kernel: one digit pass over random keys, the quantity
-    # calibrate.py inverts --sort-bench rows to (kernel ms / Mtuples / pass)
-    from tpu_radix_join.ops.pallas.merge_scan import pallas_available
-    if pallas_available():
-        from tpu_radix_join.ops.pallas.radix_sort import radix_pass_slots_pallas
-        radix_elems = 1 << 24
-        rkeys = jnp.asarray(np.random.default_rng(1).integers(
-            0, 1 << 32, radix_elems, dtype=np.uint32))
-        dt = timed(jax.jit(lambda a: radix_pass_slots_pallas(a, shift=0)),
-                   rkeys, iters=5)
-        updates["radix_sort_pass_unit_ms"] = {
-            "value": round(dt * 1e3 / (radix_elems / 1e6), 5),
-            "source": "calibrate:radix_slot_pass impl=pallas"}
     # dispatch floor: the trivial-program round trip
     tiny = jnp.zeros((8,), jnp.uint32)
     fn = jax.jit(lambda a: a + jnp.uint32(1))
